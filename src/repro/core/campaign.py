@@ -291,9 +291,9 @@ class Campaign:
     — the pass only *re-ranks after* the walk, it never feeds back
     into it.  ``measured_evaluator`` overrides the measured tier's
     default (kernel bench / reduced wall-clock proxy behind the disk
-    timing cache) — on real hardware pass a
-    :class:`~repro.core.trial.WallClockEvaluator` over the production
-    mesh.
+    timing cache) — on a chip host pass a
+    :class:`~repro.core.trial.WallClockEvaluator` over a mesh of the
+    host's own chips (``chip_smoke.py`` runs one on a one-chip mesh).
     """
 
     def __init__(self, cells: Sequence[CellSpec], *,

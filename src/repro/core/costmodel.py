@@ -174,15 +174,6 @@ class Roofline:
         }
 
 
-def cost_analysis_dict(compiled) -> Dict:
-    """compiled.cost_analysis() as one flat dict across JAX versions
-    (older releases return a singleton list of dicts)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):      # older JAX: one dict per program
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def roofline_from_dict(d: Dict) -> "Roofline":
     """Inverse of Roofline.as_dict (drops the derived total/bottleneck);
     used by the compile cache to rehydrate memoized measurements."""
@@ -194,7 +185,7 @@ def analyze(compiled, compute_dtype: str = "bfloat16",
             pod_size: int = 256, flash_attention_correction: float = 0.0
             ) -> Roofline:
     """Roofline terms from a compiled executable (per-chip program)."""
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
     flops = float(ca.get("flops", 0.0))
     byts = float(ca.get("bytes accessed", 0.0))
     peak = HW["flops_bf16"] if compute_dtype != "float32" else HW["flops_f32"]

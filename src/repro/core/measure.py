@@ -17,7 +17,9 @@ Three pieces:
     only, transient faults are never remembered) under
     ``results/trials/timings``.  Keys cover the *full* config dict —
     unlike the compile cache's compile-projection keys, a measured wall
-    clock depends on every knob.
+    clock depends on every knob — and the device that took the timing
+    (platform and ``device_kind``), so a CPU timing never answers a
+    lookup for the chip.
   * :class:`CachedMeasure` — wraps any measured evaluator with the
     timing cache, so repeated measured trials re-pay nothing: a cache
     hit returns the stored cost with ``cached=True, compiles=0``; a
@@ -29,9 +31,9 @@ Three pieces:
     measured tier's default evaluator: kernel cells time their jitted
     kernel (core/kernel_cell.py, interpret mode on CPU), step cells
     time the *reduced runnable proxy* of their step on a single-device
-    host mesh (this container is CPU-only; on real hardware pass a
-    :class:`~repro.core.trial.WallClockEvaluator` over the production
-    mesh as ``Campaign(measured_evaluator=...)`` instead).
+    host mesh, which times the CPU and not the chip (on a chip host pass
+    a :class:`~repro.core.trial.WallClockEvaluator` over a mesh of the
+    host's own chips as ``Campaign(measured_evaluator=...)`` instead).
 """
 from __future__ import annotations
 
@@ -64,13 +66,21 @@ class TimingCache(CompileCache):
         super().__init__(directory or TIMING_DIR, mem_entries, use_disk)
 
 
+def device_tag() -> str:
+    """``platform/device_kind`` of the default backend's first device:
+    the device a measured evaluation runs on."""
+    import jax
+    d = jax.devices()[0]
+    return f"{d.platform}/{d.device_kind}"
+
+
 def measure_key(wl: Workload, rt: TunableConfig, repeats: int,
                 tag: str = MEASURE_VERSION) -> str:
     """Cache key of one measured evaluation: the cell, the *full*
-    config (every knob can move a wall clock), the repeat count and the
-    protocol version tag."""
-    blob = json.dumps([tag, wl.key(), int(repeats), rt.as_dict()],
-                      sort_keys=True, default=str)
+    config (every knob can move a wall clock), the repeat count, the
+    protocol version tag and the device (:func:`device_tag`)."""
+    blob = json.dumps([tag, device_tag(), wl.key(), int(repeats),
+                       rt.as_dict()], sort_keys=True, default=str)
     h = hashlib.sha1(blob.encode()).hexdigest()[:16]
     return f"{wl.key()}__measured__{h}"
 

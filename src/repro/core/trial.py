@@ -6,7 +6,8 @@ observes a scalar cost.
 
 Two evaluators:
   * RooflineEvaluator — lower+compile on the production mesh, cost =
-    analytic roofline step time (CPU-only infrastructure, DESIGN.md §2.2).
+    analytic roofline step time (compile-only, on the CPU backend's
+    placeholder devices even on a chip host, DESIGN.md §2.2).
     A config whose compiled peak memory exceeds per-chip HBM *crashes*,
     exactly like the paper's sort-by-key 0.1/0.7 run.
   * WallClockEvaluator — median of N real executions (the paper's
@@ -274,8 +275,8 @@ class RooflineEvaluator:
     def __init__(self, mesh_factory: Callable = None, use_cache: bool = True,
                  hbm_limit: float = None,
                  compile_cache: Optional[CompileCache] = None):
-        from repro.launch.mesh import make_production_mesh
-        self._mesh_factory = mesh_factory or make_production_mesh
+        from repro.launch.mesh import placeholder_production_mesh
+        self._mesh_factory = mesh_factory or placeholder_production_mesh
         self.use_cache = use_cache
         self.hbm_limit = hbm_limit or costmodel.HW["hbm_per_chip"]
         self.compile_cache = compile_cache or \
@@ -438,7 +439,9 @@ class WallClockEvaluator:
 
     ``make_args`` defaults to zero-filled concrete arguments derived
     from the step bundle; ``mesh_factory`` defaults to the production
-    mesh (real hardware).  Tile knobs are validated against the cell's
+    mesh over the default backend's devices, which only a 256-chip pod
+    has — on a one- or four-chip host pass a factory over those chips.
+    Tile knobs are validated against the cell's
     sequence length up front, so a non-dividing ``attn_block_q/kv`` is
     a clean deterministic-crash trial, not a Pallas grid assertion.
     """

@@ -13,10 +13,9 @@ from typing import Optional, Tuple
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.checkpoint import checkpoint as ckpt
-from repro.compat import axis_types_kw
 
 
 def remesh(n_devices: int, model_axis: int,
@@ -29,7 +28,7 @@ def remesh(n_devices: int, model_axis: int,
         model_axis //= 2
     data = n_devices // model_axis
     grid = np.array(devices[:data * model_axis]).reshape(data, model_axis)
-    return Mesh(grid, ("data", "model"), **axis_types_kw(2))
+    return Mesh(grid, ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 
 
 def restore_resharded(directory: str, step: int, target_tree, new_shardings):

@@ -8,7 +8,8 @@ VMEM working set and the HBM re-fetch factor for K/V.
 
 Grid: (B, H, S/block_q, S/block_kv); the last axis is sequential on TPU,
 so the online-softmax state (m, l, acc) lives in VMEM scratch across
-KV steps.  Causal Q-tiles skip fully-masked KV tiles (@pl.when).
+KV steps.  Causal Q-tiles skip fully-masked KV tiles (@pl.when).  Keys
+at or past ``kv_len`` (a padded tail) are masked out.
 """
 from __future__ import annotations
 
@@ -25,7 +26,7 @@ NEG_INF = -1e30
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
                   *, scale: float, block_q: int, block_kv: int,
-                  causal: bool):
+                  causal: bool, kv_len: int):
     qi = pl.program_id(2)
     kj = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -47,12 +48,14 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         k = k_ref[0, 0].astype(jnp.float32)                # (bk, hd)
         v = v_ref[0, 0].astype(jnp.float32)                # (bk, hd)
         s = q @ k.T                                        # (bq, bk)
+        cols = k_start + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_kv), 1)
         if causal:
             rows = q_start + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_kv), 0)
-            cols = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_kv), 1)
             s = jnp.where(rows >= cols, s, NEG_INF)
+        else:
+            s = jnp.where(cols < kv_len, s, NEG_INF)
         m_prev = m_ref[...]                                # (bq, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)                             # (bq, bk)
@@ -68,11 +71,14 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q",
-                                             "block_kv", "interpret"))
+                                             "block_kv", "kv_len",
+                                             "interpret"))
 def flash_attention_bhsd(q, k, v, *, causal: bool = True,
                          block_q: int = 128, block_kv: int = 128,
-                         interpret: bool = False):
-    """q/k/v: (B, H, S, hd) -> (B, H, S, hd)."""
+                         kv_len: int = None, interpret: bool = False):
+    """q/k/v: (B, H, S, hd) -> (B, H, S, hd).  ``kv_len`` (default S)
+    is the number of real keys when S carries a padded tail; under
+    ``causal`` the mask already hides that tail from the real rows."""
     B, H, S, hd = q.shape
     block_q = min(block_q, S)
     block_kv = min(block_kv, S)
@@ -80,7 +86,8 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True,
     scale = 1.0 / math.sqrt(hd)
     grid = (B, H, S // block_q, S // block_kv)
     kernel = functools.partial(_flash_kernel, scale=scale, block_q=block_q,
-                               block_kv=block_kv, causal=causal)
+                               block_kv=block_kv, causal=causal,
+                               kv_len=S if kv_len is None else kv_len)
     return pl.pallas_call(
         kernel,
         grid=grid,

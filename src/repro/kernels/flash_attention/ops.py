@@ -1,33 +1,29 @@
 """Jitted public wrapper: (B, S, H, hd) layout used by the model zoo.
 
-On CPU (tests, this container) the kernel body runs in interpret mode;
-on TPU it compiles to Mosaic.  The XLA reference path stays the dry-run
+On the CPU backend (tests) the kernel body runs in interpret mode; on
+the TPU it compiles to Mosaic (kernels/tiling.interpret_mode).  The XLA reference path stays the dry-run
 default so cost_analysis reflects honest HLO (DESIGN.md §2.2).
 """
 from __future__ import annotations
 
-import jax
+import jax.numpy as jnp
 
+from repro.kernels import tiling
 from repro.kernels.flash_attention.flash_attention import flash_attention_bhsd
-from repro.kernels.tiling import fit_block
-
-
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
                     block_kv: int = 128):
     """q/k/v: (B, S, H, hd) (kv already GQA-repeated) -> (B, S, H, hd).
 
-    Blocks are fitted to the largest divisor of S <= the request, so
-    ragged sequence lengths stay correct (kernels require block | S)."""
+    Blocks are fitted to the dtype's tile (kernels/tiling.py); a ragged
+    S with no aligned divisor is padded at the end and sliced back."""
     S = q.shape[1]
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    o = flash_attention_bhsd(qt, kt, vt, causal=causal,
-                             block_q=fit_block(block_q, S),
-                             block_kv=fit_block(block_kv, S),
-                             interpret=_on_cpu())
-    return o.transpose(0, 2, 1, 3)
+    S_pad, (bq, bk) = tiling.fit_blocks(S, (block_q, block_kv),
+                                        tiling.sublane_tile(q.dtype))
+    pad = ((0, 0), (0, 0), (0, S_pad - S), (0, 0))
+    qt, kt, vt = (jnp.pad(t.transpose(0, 2, 1, 3), pad) for t in (q, k, v))
+    o = flash_attention_bhsd(qt, kt, vt, causal=causal, block_q=bq,
+                             block_kv=bk, kv_len=S,
+                             interpret=tiling.interpret_mode())
+    return o[:, :, :S].transpose(0, 2, 1, 3)

@@ -28,9 +28,7 @@ def rmsnorm_2d(x, scale, *, eps: float = 1e-5, block_rows: int = 256,
     """x: (N, d), scale: (d,) -> (N, d)."""
     N, d = x.shape
     block_rows = min(block_rows, N)
-    while N % block_rows:
-        block_rows //= 2
-    block_rows = max(block_rows, 1)
+    assert N % block_rows == 0, (N, block_rows)
     return pl.pallas_call(
         functools.partial(_rmsnorm_kernel, eps=eps),
         grid=(N // block_rows,),

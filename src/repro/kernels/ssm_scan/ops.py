@@ -1,31 +1,33 @@
 """Jitted public wrapper: model-zoo layout (B,S,H,P) -> kernel layout."""
 from __future__ import annotations
 
-import jax
+import jax.numpy as jnp
 
+from repro.kernels import tiling
 from repro.kernels.ssm_scan.ssm_scan import ssm_scan_grid
-from repro.kernels.tiling import fit_block
-
-
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
 
 
 def ssm_scan(X, Bm, Cm, dt, la, *, chunk: int = 256):
     """X: (B,S,H,P); Bm/Cm: (B,S,N); dt/la: (B,S,H) -> (Y, h_final).
 
-    The chunk is fitted to the largest divisor of S <= the request, so
-    ragged sequence lengths stay correct (the grid requires chunk | S)."""
+    The chunk is fitted to the tile of X's dtype (kernels/tiling.py).
+    A sequence with no aligned divisor is padded at the end with steps
+    of zero input and zero log decay, which leave the state unchanged,
+    so the final state is exact and Y is sliced back to S."""
     B, S, H, P = X.shape
     N = Bm.shape[-1]
-    chunk = fit_block(chunk, S)
-    nc = S // chunk
-    Xg = X.reshape(B, nc, chunk, H, P).transpose(0, 3, 1, 2, 4)
-    Bg = Bm.reshape(B, nc, chunk, N)
-    Cg = Cm.reshape(B, nc, chunk, N)
-    dtg = dt.reshape(B, nc, chunk, H).transpose(0, 3, 1, 2)
-    lag = la.reshape(B, nc, chunk, H).transpose(0, 3, 1, 2)
+    S_pad, (chunk,) = tiling.fit_blocks(S, (chunk,),
+                                        tiling.sublane_tile(X.dtype))
+    nc = S_pad // chunk
+    pad = lambda t: jnp.pad(t, ((0, 0), (0, S_pad - S))
+                            + ((0, 0),) * (t.ndim - 2))
+    Xg = pad(X).reshape(B, nc, chunk, H, P).transpose(0, 3, 1, 2, 4)
+    Bg = pad(Bm).reshape(B, nc, chunk, N)
+    Cg = pad(Cm).reshape(B, nc, chunk, N)
+    # (B, H, nc, 1, Q): each chunk's step sizes are one whole row
+    dtg = pad(dt).reshape(B, nc, 1, chunk, H).transpose(0, 4, 1, 2, 3)
+    lag = pad(la).reshape(B, nc, 1, chunk, H).transpose(0, 4, 1, 2, 3)
     Y, hF = ssm_scan_grid(Xg, Bg, Cg, dtg, lag, chunk=chunk,
-                          interpret=_on_cpu())
-    Y = Y.transpose(0, 2, 3, 1, 4).reshape(B, S, H, P)
+                          interpret=tiling.interpret_mode())
+    Y = Y.transpose(0, 2, 3, 1, 4).reshape(B, S_pad, H, P)[:, :S]
     return Y, hF

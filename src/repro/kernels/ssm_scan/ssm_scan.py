@@ -11,8 +11,10 @@ Grid: (B, H, S/chunk).  Layouts prepared by ops.py:
   X  (B, H, nc, Q, P)   token inputs (head-split)
   Bm (B, nc, Q, N)      input projections (shared across heads)
   Cm (B, nc, Q, N)      output projections (shared across heads)
-  dt (B, H, nc, Q)      step sizes
-  la (B, H, nc, Q)      log decay (dt * A)
+  dt (B, H, nc, 1, Q)   step sizes
+  la (B, H, nc, 1, Q)   log decay (dt * A)
+The singleton axis makes each dt/la block a whole (1, Q) row, which
+meets Mosaic's tiling rule for any number of chunks.
 Outputs: Y (B, H, nc, Q, P); final state (B, H, P, N).
 """
 from __future__ import annotations
@@ -37,25 +39,37 @@ def _ssd_kernel(x_ref, b_ref, c_ref, dt_ref, la_ref, y_ref, hout_ref,
     X = x_ref[0, 0, 0].astype(jnp.float32)          # (Q, P)
     Bm = b_ref[0, 0].astype(jnp.float32)            # (Q, N)
     Cm = c_ref[0, 0].astype(jnp.float32)            # (Q, N)
-    dt = dt_ref[0, 0, 0].astype(jnp.float32)        # (Q,)
-    la = la_ref[0, 0, 0].astype(jnp.float32)        # (Q,)
+    dt = dt_ref[0, 0, 0].astype(jnp.float32)        # (1, Q)
+    la = la_ref[0, 0, 0].astype(jnp.float32)        # (1, Q)
 
-    cum = jnp.cumsum(la)                             # (Q,)
-    # within-chunk: scores[t, j] = (C_t . B_j) * exp(cum_t - cum_j) * dt_j
-    Lmat = jnp.exp(cum[:, None] - cum[None, :])
+    # Mosaic has no cumsum and no vector transpose, so the prefix sums
+    # and the (1, Q) -> (Q, 1) turns are masked row/column reductions
+    # over the (Q, Q) grid: exact f32 adds on the vector unit.
     rows = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    Lmat = jnp.where(rows >= cols, Lmat, 0.0)
-    G = Cm @ Bm.T                                    # (Q, Q)
-    scores = G * Lmat * dt[None, :]
+    causal = rows >= cols
+    diag = rows == cols
+    cum_c = jnp.sum(jnp.where(causal, la, 0.0), axis=1,
+                    keepdims=True)                   # (Q, 1) prefix sums
+    cum_r = jnp.sum(jnp.where(diag, cum_c, 0.0), axis=0,
+                    keepdims=True)                   # (1, Q)
+    dt_c = jnp.sum(jnp.where(diag, dt, 0.0), axis=1,
+                   keepdims=True)                    # (Q, 1)
+    # within-chunk: scores[t, j] = (C_t . B_j) * exp(cum_t - cum_j) * dt_j
+    Lmat = jnp.exp(jnp.where(causal, cum_c - cum_r, -jnp.inf))
+    G = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())))   # (Q, Q)
+    scores = G * Lmat * dt
     y = scores @ X                                   # (Q, P) intra
     # inter-chunk: y_t += exp(cum_t) * C_t . h_prev
     h = h_ref[...]                                   # (P, N)
-    y = y + jnp.exp(cum)[:, None] * (Cm @ h.T)
+    y = y + jnp.exp(cum_c) * jax.lax.dot_general(
+        Cm, h, (((1,), (1,)), ((), ())))
     y_ref[0, 0, 0] = y.astype(y_ref.dtype)
     # state update: h = exp(cum_last) * h + sum_j w_j X_j (x) B_j
-    w = dt * jnp.exp(cum[-1] - cum)                  # (Q,)
-    h_ref[...] = jnp.exp(cum[-1]) * h + (w[:, None] * X).T @ Bm
+    cum_last = cum_c[chunk - 1:chunk, :]             # (1, 1)
+    w = dt_c * jnp.exp(cum_last - cum_c)             # (Q, 1)
+    h_ref[...] = jnp.exp(cum_last) * h + jax.lax.dot_general(
+        w * X, Bm, (((0,), (0,)), ((), ())))
 
     @pl.when(ci == nc - 1)
     def _emit_state():
@@ -77,8 +91,8 @@ def ssm_scan_grid(X, Bm, Cm, dt, la, *, chunk: int = 256,
             pl.BlockSpec((1, 1, 1, Q, P), lambda b, h, c: (b, h, c, 0, 0)),
             pl.BlockSpec((1, 1, Q, N), lambda b, h, c: (b, c, 0, 0)),
             pl.BlockSpec((1, 1, Q, N), lambda b, h, c: (b, c, 0, 0)),
-            pl.BlockSpec((1, 1, 1, Q), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, 1, 1, Q), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, 1, 1, Q), lambda b, h, c: (b, h, c, 0, 0)),
+            pl.BlockSpec((1, 1, 1, 1, Q), lambda b, h, c: (b, h, c, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, 1, Q, P), lambda b, h, c: (b, h, c, 0, 0)),

@@ -19,7 +19,7 @@ from repro.configs import (get_config, get_shape, list_archs, SHAPES,
                            shape_applicable)
 from repro.core import costmodel
 from repro.core.params import TunableConfig, default_config
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import placeholder_production_mesh
 from repro.runtime.stepfn import build_step
 
 RESULTS_DIR = pathlib.Path(__file__).resolve().parents[3] / "results" / "dryrun"
@@ -55,7 +55,7 @@ def run_cell(arch: str, shape_id: str, multi_pod: bool,
         return rec
     rt = rt or infra_default_rt(arch)
     rec["tunable"] = rt.as_dict()
-    mesh = make_production_mesh(multi_pod=multi_pod)
+    mesh = placeholder_production_mesh(multi_pod=multi_pod)
     chips = mesh.devices.size
     t0 = time.time()
     try:

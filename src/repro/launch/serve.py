@@ -1,7 +1,9 @@
 """Batched serving driver: prefill a prompt batch, decode N tokens.
 
-CPU (this container): ``--reduced`` serves a reduced config for real.
-The full configs' serve_step is exercised via the dry-run.
+CPU: ``--reduced`` serves a reduced config for real.  One TPU chip:
+the full config (``chip_smoke.py`` runs smollm-135m this way).  Attention
+takes the XLA path; ``serving.BatchScheduler`` with ``attn_impl="pallas"``
+is the path that runs the Pallas kernels.
 """
 from __future__ import annotations
 
@@ -15,6 +17,7 @@ import jax.numpy as jnp
 from repro.configs import get_config, get_reduced
 from repro.configs.base import ShapeConfig
 from repro.core.params import default_config
+from repro.launch.chip import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.model import build_model, synth_inputs
 
@@ -29,6 +32,7 @@ def main(argv=None) -> int:
     ap.add_argument("--kv-dtype", default="bfloat16")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     rt = default_config(compute_dtype="bfloat16",

@@ -1,16 +1,23 @@
 """End-to-end training driver: data pipeline -> sharded train step ->
 checkpoints, preemption-safe restart, straggler watchdog.
 
-CPU (this container): ``--reduced`` trains a reduced config for real.
-TPU fleet: the same driver with the production mesh and a full config.
+CPU: ``--reduced`` trains a reduced config for real.  One TPU chip:
+the full config on a one-device mesh (``chip_smoke.py`` runs it).  TPU
+fleet: the same driver with the production mesh and a full config.
 
 Exit code 42 = preempted-after-checkpoint (relaunch with the same args;
 --resume is implicit: the driver always resumes from the latest
-checkpoint in --ckpt-dir if one exists).
+checkpoint in --ckpt-dir if one exists).  A checkpoint that already
+holds step ``--steps - 1`` or later is an error (exit 1): the run would
+train nothing.  Every step appends ``{step, loss, grad_norm, step_s}``
+to ``<ckpt-dir>/metrics.jsonl``; the first step's time includes its
+compile.
 """
 from __future__ import annotations
 
 import argparse
+import json
+import pathlib
 import sys
 import time
 
@@ -25,10 +32,14 @@ from repro.core.params import default_config
 from repro.data.pipeline import SyntheticLM
 from repro.ft.preemption import PreemptionHandler, RESTART_EXIT_CODE
 from repro.ft.straggler import StragglerDetector
+from repro.launch.chip import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.model import build_model
 from repro.optim.optimizers import cosine_schedule, make_optimizer
 from repro.runtime.stepfn import build_train_step
+
+CKPT_ROOT = pathlib.Path(__file__).resolve().parents[3] / "results" / \
+    "checkpoints"
 
 
 def main(argv=None) -> int:
@@ -40,7 +51,9 @@ def main(argv=None) -> int:
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--lr", type=float, default=3e-4)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir", default=None,
+                    help="default: results/checkpoints/<arch> in the "
+                         "checkout")
     ap.add_argument("--ckpt-interval", type=int, default=20)
     ap.add_argument("--log-interval", type=int, default=5)
     ap.add_argument("--production-mesh", action="store_true")
@@ -50,6 +63,7 @@ def main(argv=None) -> int:
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     shape = ShapeConfig("cli", args.seq, args.batch, "train")
@@ -68,7 +82,9 @@ def main(argv=None) -> int:
     model = build_model(cfg)
 
     pre = PreemptionHandler().install()
-    mgr = CheckpointManager(args.ckpt_dir, interval=args.ckpt_interval)
+    ckpt_dir = pathlib.Path(args.ckpt_dir or CKPT_ROOT / cfg.name)
+    ckpt_dir.mkdir(parents=True, exist_ok=True)
+    mgr = CheckpointManager(str(ckpt_dir), interval=args.ckpt_interval)
     watchdog = StragglerDetector(factor=3.0)
 
     with mesh:
@@ -82,10 +98,18 @@ def main(argv=None) -> int:
                 jax.tree.map(lambda s: np.zeros(s.shape, s.dtype), target))
             params, opt_state = state["params"], state["opt"]
             start += 1
+            if start >= args.steps:
+                print(f"error: the checkpoint in {ckpt_dir} is at step "
+                      f"{start - 1}, past --steps {args.steps}: nothing "
+                      "to train", file=sys.stderr, flush=True)
+                return 1
         else:
             params = model.init(jax.random.PRNGKey(args.seed))
             opt_state = optimizer.init(params)
             start = 0
+        p_sh, o_sh, _ = bundle.in_shardings
+        params = jax.device_put(params, p_sh)
+        opt_state = jax.device_put(opt_state, o_sh)
 
         data = SyntheticLM(cfg, shape, rt, mesh, seed=args.seed)
         host = "host0"
@@ -97,6 +121,11 @@ def main(argv=None) -> int:
             jax.block_until_ready(metrics["loss"])
             dt_step = time.time() - t0
             watchdog.heartbeat(host, step, dt_step)
+            with open(ckpt_dir / "metrics.jsonl", "a") as f:
+                f.write(json.dumps({
+                    "step": step, "loss": float(metrics["loss"]),
+                    "grad_norm": float(metrics["grad_norm"]),
+                    "step_s": dt_step}) + "\n")
             if step == start:
                 print(f"first step (incl. compile): "
                       f"{time.time()-t_compile:.1f}s", flush=True)

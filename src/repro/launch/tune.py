@@ -35,6 +35,11 @@ shared mount):
   * ``--worker`` — join an existing shared directory (``--dir``) as
     one worker; start any number, anywhere, any time.
 
+A TPU chip belongs to one process at a time, and the measured tier
+runs on it.  So on a chip host, ``--workers N`` / ``--coordinate``
+with N > 1 and ``--measure-top-k`` is refused: run the campaign in one
+process instead.  The coordinator itself never starts a JAX backend.
+
 ``--warm-start`` seeds each fresh cell's cursor from the best configs
 of the nearest already-tuned cells in the shared ``history.jsonl``
 trial store (core/history.py); every campaign appends to that store,
@@ -116,6 +121,7 @@ import sys
 from repro.core import report
 from repro.core.params import SENSITIVITY_SWEEP, default_config
 from repro.core.trial import RooflineEvaluator, TrialRunner, Workload
+from repro.launch import chip
 
 RESULTS_DIR = pathlib.Path(__file__).resolve().parents[3] / "results" / "tuning"
 
@@ -689,6 +695,7 @@ def main(argv=None) -> int:
                           "live telemetry metrics as one JSON object "
                           "on stdout (machine-readable)")
     args = ap.parse_args(argv)
+    chip.enable_compile_cache()
 
     if args.sweep_knobs and args.strategy != "sensitivity":
         ap.error("--sweep-knobs only applies to --strategy sensitivity")
@@ -787,6 +794,12 @@ def main(argv=None) -> int:
     if args.measured_evaluator and not args.measure_top_k:
         ap.error("--measured-evaluator requires --measure-top-k > 0")
     fabric_mode = args.worker or args.coordinate or args.workers
+    if (args.coordinate or args.workers) and args.measure_top_k \
+            and (args.workers or 2) > 1 and chip.tpu_host():
+        ap.error("--measure-top-k times trials on the TPU chip, which "
+                 "belongs to one process at a time: on a chip host run "
+                 "the campaign in one process (drop --workers / "
+                 "--coordinate)")
     if args.trace and not (args.all or args.cells or fabric_mode):
         ap.error("--trace records telemetry into the campaign "
                  "directory; it applies to campaign/fabric modes "

@@ -118,12 +118,15 @@ def rope(x, positions, theta: float):
 
 # ---------------------------------------------------------------- attention
 def attn_spec(cfg) -> Dict[str, PSpec]:
+    # explicit fan-in scales: "fan_in" reads shape[-2], which for these
+    # head-split weights is a head count, not the contracted width
     d, H, Hkv, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    s_in, s_out = 1.0 / math.sqrt(d), 1.0 / math.sqrt(H * hd)
     return {
-        "wq": PSpec((d, H, hd), ("embed", "heads", None)),
-        "wk": PSpec((d, Hkv, hd), ("embed", "kv_heads", None)),
-        "wv": PSpec((d, Hkv, hd), ("embed", "kv_heads", None)),
-        "wo": PSpec((H, hd, d), ("heads", None, "embed")),
+        "wq": PSpec((d, H, hd), ("embed", "heads", None), s_in),
+        "wk": PSpec((d, Hkv, hd), ("embed", "kv_heads", None), s_in),
+        "wv": PSpec((d, Hkv, hd), ("embed", "kv_heads", None), s_in),
+        "wo": PSpec((H, hd, d), ("heads", None, "embed"), s_out),
     }
 
 

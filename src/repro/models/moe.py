@@ -20,7 +20,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.params import TunableConfig
 from repro.models import layers as L
 
@@ -182,7 +181,7 @@ def moe_mlp(p, x, cfg, rt: TunableConfig, rules):
             return y.reshape(B_local, S_local, d), aux
 
         xspec = P(batch_axes or None, "model", None)
-        f = compat.shard_map(
+        f = jax.shard_map(
             body, mesh=mesh,
             in_specs=(xspec, P(None, None),
                       P("model", fsdp_in_mesh or None, None),
@@ -219,7 +218,7 @@ def moe_mlp(p, x, cfg, rt: TunableConfig, rules):
         return y.reshape(B_local, S, d), aux
 
     xspec = P(batch_axes or None, None, None)
-    f = compat.shard_map(
+    f = jax.shard_map(
         body_g, mesh=mesh,
         in_specs=(xspec, P(None, None),
                   P("model", fsdp_in_mesh or None, None),

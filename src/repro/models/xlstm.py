@@ -9,6 +9,7 @@ Sub-quadratic: runs the long_500k cell.
 """
 from __future__ import annotations
 
+import math
 from typing import Dict
 
 import jax
@@ -23,11 +24,12 @@ from repro.runtime.loops import scan_layers
 # ----------------------------------------------------------- mLSTM
 def mlstm_spec(cfg) -> Dict[str, L.PSpec]:
     d, H, hd = cfg.d_model, cfg.n_heads, cfg.hd
+    s_in = 1.0 / math.sqrt(d)      # "fan_in" would read the head count
     return {
         "ln": L.rmsnorm_spec(d),
-        "wq": L.PSpec((d, H, hd), ("embed", None, None)),
-        "wk": L.PSpec((d, H, hd), ("embed", None, None)),
-        "wv": L.PSpec((d, H, hd), ("embed", None, "ssm_inner")),
+        "wq": L.PSpec((d, H, hd), ("embed", None, None), s_in),
+        "wk": L.PSpec((d, H, hd), ("embed", None, None), s_in),
+        "wv": L.PSpec((d, H, hd), ("embed", None, "ssm_inner"), s_in),
         "wi": L.PSpec((d, H), ("embed", None)),
         "wf": L.PSpec((d, H), ("embed", None)),
         "wog": L.PSpec((d, d), ("embed", None)),

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ArchConfig, ShapeConfig
 from repro.core.params import TunableConfig
 from repro.models import layers as L
@@ -53,6 +52,10 @@ class StepBundle:
     rules: ShardingRules
     kind: str                     # train | prefill | decode
     notes: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    # shardings of ``args``: place concrete arguments with them before
+    # the first call (an uncommitted array misses the jit cache once
+    # its committed successor comes back, and the step compiles twice)
+    in_shardings: Tuple = ()
 
     def lower(self):
         return self.fn.lower(*self.args)
@@ -163,10 +166,10 @@ def build_train_step(cfg: ArchConfig, shape: ShapeConfig, rt: TunableConfig,
                                                      - 1)))
                       for k in b_shapes}
         ef_spec = P(data_axes, None)
-        sm = compat.shard_map(local_grads, mesh=mesh,
-                              in_specs=(p_specs, in_b_specs, ef_spec),
-                              out_specs=(P(), p_specs, ef_spec),
-                              check_vma=False)
+        sm = jax.shard_map(local_grads, mesh=mesh,
+                           in_specs=(p_specs, in_b_specs, ef_spec),
+                           out_specs=(P(), p_specs, ef_spec),
+                           check_vma=False)
 
         if ef:
             # augment the optimizer state with the per-shard EF residual
@@ -225,7 +228,8 @@ def build_train_step(cfg: ArchConfig, shape: ShapeConfig, rt: TunableConfig,
     args = (p_shapes, o_shapes, b_shapes)
     return StepBundle(jitted, args, rules, "train",
                       notes={"explicit_comm": explicit,
-                             "sharding_notes": list(rules.notes)})
+                             "sharding_notes": list(rules.notes)},
+                      in_shardings=(p_sh, o_sh, b_sh))
 
 
 # ===================================================================== serve
@@ -242,7 +246,8 @@ def build_prefill_step(cfg: ArchConfig, shape: ShapeConfig,
 
     jitted = jax.jit(step, in_shardings=(p_sh, b_sh))
     return StepBundle(jitted, (p_shapes, b_shapes), rules, "prefill",
-                      notes={"sharding_notes": list(rules.notes)})
+                      notes={"sharding_notes": list(rules.notes)},
+                      in_shardings=(p_sh, b_sh))
 
 
 def build_decode_step(cfg: ArchConfig, shape: ShapeConfig,
@@ -264,7 +269,8 @@ def build_decode_step(cfg: ArchConfig, shape: ShapeConfig,
                      out_shardings=(None, c_sh),
                      donate_argnums=donate)
     return StepBundle(jitted, (p_shapes, c_shapes, t_shape), rules, "decode",
-                      notes={"sharding_notes": list(rules.notes)})
+                      notes={"sharding_notes": list(rules.notes)},
+                      in_shardings=(p_sh, c_sh, t_sh))
 
 
 def build_step(cfg: ArchConfig, shape: ShapeConfig, rt: TunableConfig,

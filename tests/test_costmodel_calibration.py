@@ -21,7 +21,7 @@ def _scan_flops(n, unroll):
     x = jax.ShapeDtypeStruct((64, 64), jnp.float32)
     ws = jax.ShapeDtypeStruct((n, 64, 64), jnp.float32)
     compiled = jax.jit(f).lower(x, ws).compile()
-    return float(costmodel.cost_analysis_dict(compiled)["flops"])
+    return float(compiled.cost_analysis()["flops"])
 
 
 def test_while_body_counted_once():

@@ -100,18 +100,30 @@ def test_flash_attention_noncausal():
 
 
 # ------------------------------------------------- ragged shapes (PR 7)
-# The public wrappers fit any requested block to the largest divisor of
-# the gridded dim (kernels/tiling.py) — sequence lengths that are NOT a
-# multiple of the tile must stay correct, not assert-crash.
+# The public wrappers fit any requested block to the dtype's tile
+# (kernels/tiling.py) and pad a length with no aligned divisor —
+# sequence lengths that are NOT a multiple of the tile must stay
+# correct, not assert-crash, and Mosaic only ever sees aligned blocks.
 
 def test_fit_block():
-    from repro.kernels.tiling import fit_block
+    from repro.kernels.tiling import fit_block, fit_blocks
     assert fit_block(128, 256) == 128      # divides: identity
     assert fit_block(512, 256) == 256      # clamp to n
-    assert fit_block(128, 192) == 96       # largest divisor <= 128
-    assert fit_block(128, 97) == 97        # prime: clamp wins
-    assert fit_block(64, 97) == 1          # prime, block < n: degenerate
-    assert fit_block(0, 64) == 1
+    assert fit_block(128, 192) == 96       # largest aligned divisor
+    assert fit_block(128, 97) == 104       # one block, rounded up to 8
+    assert fit_block(64, 97) == 64         # no aligned divisor: pad
+    assert fit_block(0, 64) == 8           # request floors at the tile
+    assert fit_block(128, 300, align=16) == 128
+    assert fit_block(128, 7, align=16) == 16
+    for align in (8, 16, 32):
+        for n in (1, 7, 97, 100, 192, 300, 1003, 4096):
+            for block in (0, 64, 100, 128, 512):
+                b = fit_block(block, n, align)
+                assert b % align == 0 and b >= align
+    assert fit_blocks(192, (128, 128), 8) == (192, (96, 96))
+    assert fit_blocks(300, (128, 64), 16) == (384, (128, 64))
+    assert fit_blocks(300, (256, 64), 16) == (512, (256, 64))
+    assert fit_blocks(100, (128, 128), 16) == (112, (112, 112))
 
 
 @pytest.mark.parametrize("S", [192, 96, 300])

@@ -122,6 +122,22 @@ def test_cached_measure_roundtrip(tmp_path):
     assert measure_key(wl, rt, 3) != measure_key(wl, rt, 5)
 
 
+def test_cpu_timing_never_answers_for_the_chip(tmp_path, monkeypatch):
+    """The timing key names the device: an entry a CPU run stored on
+    disk is a miss for a TPU v5e lookup of the same cell and config."""
+    import repro.core.measure as measure
+    wl = Workload("smollm-135m", "train_4k", False)
+    rt = baseline_factory(None)
+    truth = TruthSurface()
+    assert measure.device_tag() == "cpu/cpu"
+    CachedMeasure(truth, TimingCache(tmp_path / "t"), repeats=3)(wl, rt)
+    cpu_key = measure_key(wl, rt, 3)
+    monkeypatch.setattr(measure, "device_tag", lambda: "tpu/TPU v5 lite")
+    assert measure_key(wl, rt, 3) != cpu_key
+    res = CachedMeasure(truth, TimingCache(tmp_path / "t"), repeats=3)(wl, rt)
+    assert not res.cached and len(truth.calls) == 2
+
+
 def test_cached_measure_error_memo(tmp_path):
     wl = Workload("smollm-135m", "train_4k", False)
     rt = baseline_factory(None)

@@ -103,13 +103,12 @@ def test_cost_analysis_is_per_partition():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.compat import axis_types_kw
-        mesh = jax.make_mesh((4,), ("data",), **axis_types_kw(1))
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((4,), ("data",), axis_types=(AxisType.Auto,))
         x = jax.ShapeDtypeStruct((64, 64), jnp.float32)
         f = jax.jit(lambda a: a @ a,
                     in_shardings=NamedSharding(mesh, P("data", None)))
-        from repro.core.costmodel import cost_analysis_dict
-        fl = cost_analysis_dict(f.lower(x).compile())["flops"]
+        fl = f.lower(x).compile().cost_analysis()["flops"]
         # full matmul = 2*64^3; per-partition should be ~1/4
         print(fl / (2 * 64**3))
     """)
