@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.params import TunableConfig
+from repro.models.scopes import scope
 
 
 # ---------------------------------------------------------------- params
@@ -83,6 +84,7 @@ def rmsnorm_spec(d: int) -> PSpec:
     return PSpec((d,), ("embed",), "ones")
 
 
+@scope("norm")
 def rmsnorm(x, scale, rt: TunableConfig, eps: float = 1e-5):
     if rt.attn_impl == "pallas" and x.ndim == 3:
         from repro.kernels.rmsnorm import ops as rms_ops
@@ -161,6 +163,7 @@ def full_attention(q, k, v, *, causal: bool, rt: TunableConfig, rules=None,
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
 
 
+@scope("attention")
 def attention_block(p, x, *, cfg, rt: TunableConfig, rules, positions,
                     causal=True, kv_x=None, kv_positions=None):
     """Full (train/prefill) attention sub-block.  kv_x!=None => cross-attn."""
@@ -230,6 +233,7 @@ def attn_cache_shapes(cfg, batch: int, max_seq: int, rt: TunableConfig,
     return out, lg
 
 
+@scope("attention")
 def decode_attention_block(p, x, layer_cache, pos, *, cfg, rt: TunableConfig,
                            rules):
     """One-token decode self-attention against a KV cache.
@@ -291,6 +295,7 @@ def mlp_spec(cfg, d_ff: Optional[int] = None) -> Dict[str, PSpec]:
             "wd": PSpec((ff, d), ("mlp", "embed"))}
 
 
+@scope("mlp")
 def mlp_block(p, x, *, cfg, rt: TunableConfig, rules):
     if cfg.mlp_act == "silu":
         h = jax.nn.silu(x @ cast(p["wg"], rt)) * (x @ cast(p["wu"], rt))
@@ -316,10 +321,12 @@ def embed_spec(cfg) -> Dict[str, PSpec]:
     return out
 
 
+@scope("embed")
 def embed(p, tokens, rt: TunableConfig):
     return jnp.take(cast(p["embedding"], rt), tokens, axis=0)
 
 
+@scope("head")
 def unembed(p, x, cfg, rt: TunableConfig, rules):
     w = p.get("unembed")
     if w is None:
@@ -331,6 +338,7 @@ def unembed(p, x, cfg, rt: TunableConfig, rules):
     return logits
 
 
+@scope("head")
 def xent_loss(logits, labels, cfg):
     """logits: (B,S,Vpad) f32; labels: (B,S) int32. Mean over tokens."""
     V = padded_vocab(cfg)

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from repro.core.params import TunableConfig
 from repro.models import layers as L
+from repro.models.scopes import scope
 
 
 def mamba_spec(cfg) -> Dict[str, L.PSpec]:
@@ -122,6 +123,7 @@ def ssd_chunked(X, Bm, Cm, dt, loga, chunk: int, h0=None):
     return Y.astype(X.dtype), h_final
 
 
+@scope("mamba2")
 def mamba_block(p, x, cfg, rt: TunableConfig, rules, want_state: bool = False):
     """Full Mamba2 block (train/prefill).  x: (B,S,d) -> (B,S,d).
 
@@ -137,12 +139,13 @@ def mamba_block(p, x, cfg, rt: TunableConfig, rules, want_state: bool = False):
     if rules is not None:
         xin = rules.constrain(xin, "batch", None, "ssm_inner")
     X = xin.reshape(B, S, H, P)
-    if rt.attn_impl == "pallas":
-        from repro.kernels.ssm_scan import ops as ssm_ops
-        Y, h_final = ssm_ops.ssm_scan(X, Bm, Cm, dt, loga,
-                                      chunk=cfg.ssm_chunk)
-    else:
-        Y, h_final = ssd_chunked(X, Bm, Cm, dt, loga, cfg.ssm_chunk)
+    with scope("ssd_scan"):
+        if rt.attn_impl == "pallas":
+            from repro.kernels.ssm_scan import ops as ssm_ops
+            Y, h_final = ssm_ops.ssm_scan(X, Bm, Cm, dt, loga,
+                                          chunk=cfg.ssm_chunk)
+        else:
+            Y, h_final = ssd_chunked(X, Bm, Cm, dt, loga, cfg.ssm_chunk)
     Y = Y + p["D"].astype(Y.dtype)[None, None, :, None] * X
     y = Y.reshape(B, S, d_in)
     y = L.rmsnorm(y * jax.nn.silu(z), p["gln"], rt, cfg.norm_eps)
@@ -169,6 +172,7 @@ def mamba_cache_shapes(cfg, batch: int, layers: int):
     return shp, lg
 
 
+@scope("mamba2")
 def mamba_decode_block(p, x, layer_cache, cfg, rt: TunableConfig, rules):
     """One-token recurrent update.  x: (B,1,d)."""
     B, _, d = x.shape

@@ -122,7 +122,9 @@ def prefill_fn(p, batch, cfg, rt: TunableConfig, rules, max_seq: int):
     def body(x, bp):
         x = remat.from_carry(x, rt)
         hn = L.rmsnorm(x, bp["ln1"], rt, cfg.norm_eps)
-        # k/v recomputed once for cache storage (cheap vs attention itself)
+        # the cache's k/v are the attention block's own projections
+        # again: the compiler merges the two, so on a TPU v5e they cost
+        # nothing of their own (PERF.md, section 5)
         k = jnp.einsum("bsd,dhk->bshk", hn, L.cast(bp["attn"]["wk"], rt))
         v = jnp.einsum("bsd,dhk->bshk", hn, L.cast(bp["attn"]["wv"], rt))
         k = L.rope(k, positions, cfg.rope_theta)

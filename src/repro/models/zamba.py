@@ -122,6 +122,8 @@ def prefill_fn(p, batch, cfg, rt: TunableConfig, rules, max_seq: int):
             return xc, st
         x, states = scan_layers(inner, x, gp, unroll=rt.unroll_layers)
         hn = L.rmsnorm(x, p["shared"]["ln1"], rt, cfg.norm_eps)
+        # the cache's k/v: the shared block's own projections again,
+        # merged with them by the compiler (PERF.md, section 5)
         k = jnp.einsum("bsd,dhk->bshk", hn, L.cast(p["shared"]["attn"]["wk"], rt))
         v = jnp.einsum("bsd,dhk->bshk", hn, L.cast(p["shared"]["attn"]["wv"], rt))
         k = L.rope(k, positions, cfg.rope_theta)

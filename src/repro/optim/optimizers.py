@@ -13,6 +13,8 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.models.scopes import scope
+
 
 class Optimizer(NamedTuple):
     init: Callable[[Any], Any]
@@ -61,6 +63,7 @@ def adamw(lr: Callable, b1: float = 0.9, b2: float = 0.95,
         return {"mu": zeros(), "nu": zeros(),
                 "step": jnp.zeros((), jnp.int32)}
 
+    @scope("optimizer")
     def update(grads, state, params):
         grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
         if clip_norm is not None:
@@ -109,6 +112,7 @@ def adafactor(lr: Callable, eps: float = 1e-30, clip_threshold: float = 1.0,
                                     is_leaf=lambda x: hasattr(x, "shape")),
                 "step": jnp.zeros((), jnp.int32)}
 
+    @scope("optimizer")
     def update(grads, state, params):
         step = state["step"] + 1
         t = step.astype(jnp.float32)
