@@ -63,10 +63,10 @@ WALLCLOCK_REPEATS = 3
 FOUR_CHIP_LOSS_ATOL = 2e-2
 
 #: kernel widths: (name, shape); ragged lengths have no aligned divisor
-FLASH = [("smollm-135m", (4, 2048, 9, 64)),
-         ("smollm-135m ragged", (4, 1003, 9, 64)),
-         ("zamba2-7b", (1, 4096, 32, 112)),
-         ("zamba2-7b ragged", (1, 1000, 32, 112))]
+FLASH = [("smollm-135m", (4, 2048, 9, 3, 64)),     # (B, S, H, Hkv, hd)
+         ("smollm-135m ragged", (4, 1003, 9, 3, 64)),
+         ("zamba2-7b", (1, 4096, 32, 32, 112)),
+         ("zamba2-7b ragged", (1, 1000, 32, 32, 112))]
 DECODE = [("bf16", "bfloat16", 4096, 3000), ("int8", "int8", 4096, 3000),
           ("int8 ragged", "int8", 1000, 999)]     # (B=8, H=9, Hkv=3, hd=64)
 SSM = [("zamba2-7b", 4096), ("zamba2-7b ragged", 1000)]  # H=112 P=64 N=64
@@ -309,13 +309,16 @@ def phase_kernels():
     exact = jax.default_matmul_precision("highest")
     tr = lambda t: t.transpose(0, 2, 1, 3) if t is not None else None
 
-    for name, (B, S, H, hd) in FLASH:
-        q, k, v = (jax.random.normal(kk, (B, S, H, hd)).astype(bf16)
-                   for kk in jax.random.split(key, 3))
+    for name, (B, S, H, Hkv, hd) in FLASH:
+        kq, kk, kv = jax.random.split(key, 3)
+        q = jax.random.normal(kq, (B, S, H, hd)).astype(bf16)
+        k, v = (jax.random.normal(kx, (B, S, Hkv, hd)).astype(bf16)
+                for kx in (kk, kv))
         t0 = time.time()
         out = jax.block_until_ready(fa.flash_attention(q, k, v))
+        rep = lambda t: jnp.repeat(t, H // Hkv, axis=2)
         with exact:
-            ref = tr(fa_ref.attention_ref(tr(q), tr(k), tr(v)))
+            ref = tr(fa_ref.attention_ref(tr(q), tr(rep(k)), tr(rep(v))))
         _compare(f"flash_attention {name}", out, ref, 2e-2, t0)
 
     B, H, Hkv, hd = 8, 9, 3, 64

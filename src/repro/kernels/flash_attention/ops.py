@@ -14,7 +14,8 @@ from repro.kernels.flash_attention.flash_attention import flash_attention_bhsd
 
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
                     block_kv: int = 128):
-    """q/k/v: (B, S, H, hd) (kv already GQA-repeated) -> (B, S, H, hd).
+    """q: (B, S, H, hd), k/v: (B, S, Hkv, hd) with ``H % Hkv == 0``
+    (GQA: not repeated to H heads) -> (B, S, H, hd).
 
     Blocks are fitted to the dtype's tile (kernels/tiling.py); a ragged
     S with no aligned divisor is padded at the end and sliced back."""

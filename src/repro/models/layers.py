@@ -142,12 +142,8 @@ def _repeat_kv(k, n_rep: int):
 
 def full_attention(q, k, v, *, causal: bool, rt: TunableConfig, rules=None,
                    q_positions=None, kv_positions=None):
-    """q: (B,Sq,H,hd), k/v: (B,Skv,H,hd) (already GQA-repeated)."""
-    if rt.attn_impl == "pallas" and causal and q.shape[1] == k.shape[1]:
-        from repro.kernels.flash_attention import ops as fa_ops
-        return fa_ops.flash_attention(q, k, v, causal=True,
-                                      block_q=rt.attn_block_q,
-                                      block_kv=rt.attn_block_kv)
+    """XLA attention.  q: (B,Sq,H,hd), k/v: (B,Skv,H,hd) (already
+    GQA-repeated)."""
     scale = 1.0 / math.sqrt(q.shape[-1])
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
@@ -176,8 +172,14 @@ def attention_block(p, x, *, cfg, rt: TunableConfig, rules, positions,
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions if kv_positions is None else kv_positions,
                  cfg.rope_theta)
-    k = _repeat_kv(k, cfg.n_heads // cfg.n_kv_heads)
-    v = _repeat_kv(v, cfg.n_heads // cfg.n_kv_heads)
+    causal = causal and kv_x is None
+    # the flash kernel reads each KV head once for the query heads that
+    # share it; XLA attention takes K/V repeated to the query heads
+    flash = rt.attn_impl == "pallas" and causal
+    if not flash:
+        k = _repeat_kv(k, cfg.n_heads // cfg.n_kv_heads)
+        v = _repeat_kv(v, cfg.n_heads // cfg.n_kv_heads)
+    kv_axis = "kv_heads" if flash else "heads"
     bspec = rules.attn_batch_spec(B) if rules is not None else None
     if bspec is not None:
         # beyond-paper fallback: reshard so the attention op is
@@ -187,10 +189,15 @@ def attention_block(p, x, *, cfg, rt: TunableConfig, rules, positions,
         q, k, v = resh(q), resh(k), resh(v)
     elif rules is not None:
         q = rules.constrain(q, "batch", None, "heads", None)
-        k = rules.constrain(k, "batch", None, "heads", None)
-        v = rules.constrain(v, "batch", None, "heads", None)
-    o = full_attention(q, k, v, causal=causal and kv_x is None, rt=rt,
-                       rules=rules)
+        k = rules.constrain(k, "batch", None, kv_axis, None)
+        v = rules.constrain(v, "batch", None, kv_axis, None)
+    if flash:
+        from repro.kernels.flash_attention import ops as fa_ops
+        o = fa_ops.flash_attention(q, k, v, causal=True,
+                                   block_q=rt.attn_block_q,
+                                   block_kv=rt.attn_block_kv)
+    else:
+        o = full_attention(q, k, v, causal=causal, rt=rt, rules=rules)
     if rules is not None:
         o = rules.constrain(o, "batch", None, "heads", None)
     return jnp.einsum("bshk,hkd->bsd", o, cast(p["wo"], rt))

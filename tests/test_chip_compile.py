@@ -68,16 +68,27 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype), sharding=sharding)
 
 
-@pytest.mark.parametrize("B,S,H,hd", [
-    (4, 2048, 9, 64),       # smollm-135m prefill
-    (4, 1003, 9, 64),       # ragged: no aligned divisor, padded
-    (1, 4096, 32, 112),     # zamba2-7b attention
-    (1, 1000, 32, 112)])
-def test_flash_attention_compiles(one_chip, mosaic, B, S, H, hd):
+@pytest.mark.parametrize("B,S,H,Hkv,hd,bq,bk", [
+    (4, 2048, 9, 3, 64, 128, 128),      # smollm-135m prefill
+    (16, 2048, 9, 3, 64, 128, 128),
+    (4, 1003, 9, 3, 64, 128, 128),      # ragged: no aligned divisor, padded
+    (1, 4096, 32, 32, 112, 128, 128),   # zamba2-7b attention
+    (1, 1000, 32, 32, 112, 128, 128),
+    (1, 4096, 32, 32, 112, 512, 512),
+    # one smollm-135m prefill wave, 16 x 2048, at the tile knobs' values
+    (16, 2048, 9, 3, 64, 256, 256),
+    (16, 2048, 9, 3, 64, 512, 512),
+    (16, 2048, 9, 3, 64, 512, 128),
+    (16, 2048, 9, 3, 64, 128, 512),
+    (2, 2048, 9, 1, 64, 512, 512)])     # a group of 9 split to 3 for VMEM
+def test_flash_attention_compiles(one_chip, mosaic, B, S, H, Hkv, hd, bq,
+                                  bk):
     from repro.kernels.flash_attention.ops import flash_attention
-    x = _sds((B, S, H, hd), "bfloat16", one_chip)
-    _compile(lambda q, k, v: flash_attention(q, k, v, causal=True),
-             x, x, x)
+    q = _sds((B, S, H, hd), "bfloat16", one_chip)
+    kv = _sds((B, S, Hkv, hd), "bfloat16", one_chip)
+    _compile(lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                             block_q=bq, block_kv=bk),
+             q, kv, kv)
 
 
 @pytest.mark.parametrize("S,length", [(4096, 3000), (1056, 1040)])

@@ -15,24 +15,87 @@ def tol(dtype):
         else dict(atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("B,H,S,hd", [(1, 1, 128, 64), (2, 3, 256, 64),
-                                      (1, 2, 512, 128), (2, 1, 128, 32)])
+def _qkv(key, B, S, H, Hkv, hd, dtype=jnp.float32):
+    """q with H heads and k, v with Hkv (GQA), all (B, S, heads, hd)."""
+    kq, kk, kv = jax.random.split(key, 3)
+    q = jax.random.normal(kq, (B, S, H, hd)).astype(dtype)
+    k, v = (jax.random.normal(kx, (B, S, Hkv, hd)).astype(dtype)
+            for kx in (kk, kv))
+    return q, k, v
+
+
+def _flash_ref(q, k, v, causal):
+    """``ref.attention_ref`` on K/V repeated to q's heads."""
+    rep = q.shape[2] // k.shape[2]
+    tr = lambda t: t.transpose(0, 2, 1, 3)
+    k, v = (jnp.repeat(t, rep, axis=2) for t in (k, v))
+    return tr(fa_ref.attention_ref(tr(q), tr(k), tr(v), causal=causal))
+
+
+#: (B, H, Hkv, S, hd): GQA groups 1, 3 and 4
+_FLASH_SHAPES = [(1, 1, 1, 128, 64), (2, 3, 3, 256, 64), (1, 2, 2, 512, 128),
+                 (2, 1, 1, 128, 32), (1, 9, 3, 1024, 64), (2, 4, 1, 512, 64)]
+#: (block_q, block_kv): small tiles, and the tile knobs' domain
+#: (128, 256, 512) with block_q != block_kv
+_FLASH_BLOCKS = [(64, 64), (128, 128), (64, 128), (256, 256), (512, 512),
+                 (128, 512), (512, 256)]
+
+
+@pytest.mark.parametrize("B,H,Hkv,S,hd,bq,bk", [
+    s + b for s in _FLASH_SHAPES for b in _FLASH_BLOCKS
+    if s[3] % b[0] == 0 and s[3] % b[1] == 0])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("blocks", [(64, 64), (128, 128), (64, 128)])
-def test_flash_attention_sweep(B, H, S, hd, dtype, blocks):
-    bq, bk = blocks
-    if S % bq or S % bk:
-        pytest.skip("block does not divide")
-    ks = jax.random.split(jax.random.PRNGKey(S + hd), 3)
-    q, k, v = [jax.random.normal(kk, (B, S, H, hd)).astype(dtype)
-               for kk in ks]
+def test_flash_attention_sweep(B, H, Hkv, S, hd, bq, bk, dtype):
+    q, k, v = _qkv(jax.random.PRNGKey(S + hd), B, S, H, Hkv, hd, dtype)
     out = fa_ops.flash_attention(q, k, v, causal=True, block_q=bq,
                                  block_kv=bk)
-    ref = fa_ref.attention_ref(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3), causal=True).transpose(0, 2, 1, 3)
+    ref = _flash_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **tol(dtype))
+
+
+def test_flash_attention_split_group(monkeypatch):
+    """A GQA group that overruns the VMEM budget is split: each grid
+    step then takes part of a KV head's query heads, still against
+    that KV head."""
+    from repro.kernels.flash_attention import flash_attention as fa_kernel
+    assert fa_kernel.head_group(3, 128, 128, 64, jnp.bfloat16) == 3
+    assert fa_kernel.head_group(9, 512, 512, 64, jnp.bfloat16) == 3
+    monkeypatch.setattr(fa_kernel, "VMEM_BUDGET", 0)
+    assert fa_kernel.head_group(3, 128, 128, 64, jnp.bfloat16) == 1
+    # a shape no other test compiles, so the jit cache holds no unsplit
+    # kernel for it
+    q, k, v = _qkv(jax.random.PRNGKey(11), 1, 256, 6, 2, 32)
+    out = fa_ops.flash_attention(q, k, v, causal=True, block_q=128,
+                                 block_kv=64)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_flash_ref(q, k, v, True)),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("S,bq,bk", [(2048, 128, 128), (1024, 128, 512),
+                                     (1024, 512, 128), (384, 128, 256),
+                                     (304, 304, 16)])
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_attention_pair_table(S, bq, bk, causal):
+    """The grid walks exactly the live (q tile, kv tile) pairs, each q
+    tile's run consecutive from kv tile 0 and ending on its diagonal
+    (every kv tile when not causal)."""
+    from repro.kernels.flash_attention.flash_attention import (
+        pair_table, unpack)
+    nq, nk = S // bq, S // bk
+    qi, kj, last = unpack(pair_table(nq, nk, bq, bk, causal))
+    live = [(i, j) for i in range(nq) for j in range(nk)
+            if not causal or j * bk <= i * bq + bq - 1]
+    assert sorted(zip(qi.tolist(), kj.tolist())) == live
+    for i in range(nq):
+        run = np.flatnonzero(qi == i)
+        end = min((i * bq + bq - 1) // bk, nk - 1) if causal else nk - 1
+        assert run.tolist() == list(range(run[0], run[0] + end + 1))
+        assert kj[run].tolist() == list(range(end + 1))
+        assert last[run].tolist() == [0] * end + [1]
+    if (S, bq, bk, causal) == (2048, 128, 128, True):
+        assert len(qi) == 136         # of 256 tiles
 
 
 @pytest.mark.parametrize("shape", [(8, 64), (3, 37, 512), (2, 4, 16, 128)])
@@ -88,13 +151,14 @@ def test_flash_decode_sweep(B, H, Hkv, S, hd, length, kv_dtype):
                                atol=2e-2, rtol=2e-2)
 
 
-def test_flash_attention_noncausal():
-    ks = jax.random.split(jax.random.PRNGKey(7), 3)
-    q, k, v = [jax.random.normal(kk, (2, 128, 2, 64)) for kk in ks]
-    out = fa_ops.flash_attention(q, k, v, causal=False)
-    ref = fa_ref.attention_ref(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3), causal=False).transpose(0, 2, 1, 3)
+@pytest.mark.parametrize("H,Hkv,S,bq,bk", [
+    (2, 2, 128, 128, 128), (9, 3, 512, 128, 512), (4, 1, 512, 256, 128),
+    (9, 3, 300, 128, 128)])          # ragged: a padded kv_len tail
+def test_flash_attention_noncausal(H, Hkv, S, bq, bk):
+    q, k, v = _qkv(jax.random.PRNGKey(7), 2, S, H, Hkv, 64)
+    out = fa_ops.flash_attention(q, k, v, causal=False, block_q=bq,
+                                 block_kv=bk)
+    ref = _flash_ref(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
 
@@ -128,15 +192,13 @@ def test_fit_block():
 
 @pytest.mark.parametrize("S", [192, 96, 300])
 @pytest.mark.parametrize("blocks", [(128, 128), (256, 64)])
-def test_flash_attention_ragged(S, blocks):
+@pytest.mark.parametrize("H,Hkv", [(2, 2), (9, 3)])
+def test_flash_attention_ragged(S, blocks, H, Hkv):
     bq, bk = blocks
-    ks = jax.random.split(jax.random.PRNGKey(S), 3)
-    q, k, v = [jax.random.normal(kk, (1, S, 2, 64)) for kk in ks]
+    q, k, v = _qkv(jax.random.PRNGKey(S), 1, S, H, Hkv, 64)
     out = fa_ops.flash_attention(q, k, v, causal=True, block_q=bq,
                                  block_kv=bk)
-    ref = fa_ref.attention_ref(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3), causal=True).transpose(0, 2, 1, 3)
+    ref = _flash_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                atol=2e-5, rtol=2e-5)

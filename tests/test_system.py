@@ -3,6 +3,7 @@ config trains a step (finite loss/grads) and serves (prefill+decode),
 per the smoke-test requirement; plus decode-vs-prefill consistency."""
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import get_reduced, list_archs
@@ -130,3 +131,39 @@ def test_int8_kv_cache_close_to_bf16(key):
         outs[kv] = l2
     err = float(jnp.max(jnp.abs(outs["bfloat16"] - outs["int8"])))
     assert err < 0.5, f"int8 kv cache diverges: {err}"
+
+
+@pytest.mark.parametrize("dtype,atol", [("float32", 2e-5),
+                                        ("bfloat16", 3e-2)])
+def test_attention_block_pallas_matches_xla(key, monkeypatch, dtype, atol):
+    """Causal attention through the flash kernel, which takes K/V at the
+    KV heads, == the XLA path, which takes them repeated to the query
+    heads, on a GQA config (9 query heads over 3 KV heads)."""
+    import dataclasses
+    from repro.kernels.flash_attention import ops as fa_ops
+    from repro.models import layers as L
+    cfg = dataclasses.replace(get_reduced("smollm-135m"), n_heads=9,
+                              n_kv_heads=3, head_dim=32)
+    params = L.init_params(L.attn_spec(cfg), key)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 256, cfg.d_model))
+    positions = jnp.broadcast_to(jnp.arange(256), (2, 256))
+    kv_heads = {}
+
+    def spy(impl, fn):
+        def wrapped(q, k, v, **kw):
+            kv_heads[impl] = (q.shape[2], k.shape[2], v.shape[2])
+            return fn(q, k, v, **kw)
+        return wrapped
+
+    monkeypatch.setattr(L, "full_attention", spy("xla", L.full_attention))
+    monkeypatch.setattr(fa_ops, "flash_attention",
+                        spy("pallas", fa_ops.flash_attention))
+    out = {}
+    for impl in ("xla", "pallas"):
+        rt = default_config(compute_dtype=dtype, attn_impl=impl)
+        out[impl] = L.attention_block(params, x.astype(dtype), cfg=cfg,
+                                      rt=rt, rules=None, positions=positions)
+    assert kv_heads == {"xla": (9, 9, 9), "pallas": (9, 3, 3)}
+    np.testing.assert_allclose(np.asarray(out["pallas"], np.float32),
+                               np.asarray(out["xla"], np.float32),
+                               atol=atol, rtol=atol)
