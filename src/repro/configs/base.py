@@ -27,6 +27,7 @@ class ArchConfig:
     n_experts: int = 0
     top_k: int = 0
     capacity_factor: float = 1.25
+    norm_topk_prob: bool = True  # renormalize the top-k gate values to sum 1
     # --- SSM / hybrid ---
     ssm_state: int = 0
     ssm_chunk: int = 256
@@ -42,6 +43,7 @@ class ArchConfig:
     # --- misc ---
     rope_theta: float = 10000.0
     tie_embeddings: bool = False
+    qk_norm: bool = False        # RMSNorm over the whole q and k before RoPE
     optimizer: str = "adamw"     # adamw | adafactor
     param_dtype: str = "float32"  # master-weight dtype (bf16 for 1T-scale)
     fsdp_axes: Tuple[str, ...] = ("data",)   # biggest models add "pod"
@@ -69,6 +71,8 @@ class ArchConfig:
         d, hd = self.d_model, self.hd
         qkv = d * (self.n_heads * hd) + 2 * d * (self.n_kv_heads * hd)
         attn = qkv + (self.n_heads * hd) * d
+        if self.qk_norm:
+            attn += (self.n_heads + self.n_kv_heads) * hd
         if self.mlp_act == "silu":
             mlp_dense = 3 * d * self.d_ff
         else:

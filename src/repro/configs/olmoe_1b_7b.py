@@ -1,6 +1,8 @@
 """olmoe-1b-7b — MoE, 64 experts top-8 [arXiv:2409.02060; hf].
 
-16L d_model=2048 16H (MHA kv=16) d_ff=1024(per expert) vocab=50304.
+16L d_model=2048 16H (MHA kv=16) d_ff=1024(per expert) vocab=50304;
+QK-norm over the whole q and k, top-8 gates not renormalized
+(``norm_topk_prob: false`` in the published config).
 Also the all-to-all-dominated sensitivity workload (pure-shuffling
 analogue of the paper's Sec. 4).
 """
@@ -19,6 +21,8 @@ CONFIG = ArchConfig(
     mlp_act="silu",
     n_experts=64,
     top_k=8,
+    norm_topk_prob=False,
+    qk_norm=True,
 )
 
 

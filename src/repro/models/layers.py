@@ -124,12 +124,28 @@ def attn_spec(cfg) -> Dict[str, PSpec]:
     # head-split weights is a head count, not the contracted width
     d, H, Hkv, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd
     s_in, s_out = 1.0 / math.sqrt(d), 1.0 / math.sqrt(H * hd)
-    return {
+    out = {
         "wq": PSpec((d, H, hd), ("embed", "heads", None), s_in),
         "wk": PSpec((d, Hkv, hd), ("embed", "kv_heads", None), s_in),
         "wv": PSpec((d, Hkv, hd), ("embed", "kv_heads", None), s_in),
         "wo": PSpec((H, hd, d), ("heads", None, "embed"), s_out),
     }
+    if cfg.qk_norm:
+        # QK-norm scales over the whole q (H*hd) and k (Hkv*hd) widths
+        out["q_norm"] = {"ln": PSpec((H * hd,), (None,), "ones")}
+        out["k_norm"] = {"ln": PSpec((Hkv * hd,), (None,), "ones")}
+    return out
+
+
+def qk_norm(p, t, which: str, cfg, rt: TunableConfig):
+    """QK-norm: RMSNorm of the projected q or k (``which``), (B,S,H,hd),
+    over all its heads at once, before RoPE; ``t`` itself where the
+    configuration has none."""
+    if not cfg.qk_norm:
+        return t
+    B, S, H, hd = t.shape
+    flat = rmsnorm(t.reshape(B, S, H * hd), p[which]["ln"], rt, cfg.norm_eps)
+    return flat.reshape(B, S, H, hd)
 
 
 def _repeat_kv(k, n_rep: int):
@@ -165,8 +181,10 @@ def attention_block(p, x, *, cfg, rt: TunableConfig, rules, positions,
     """Full (train/prefill) attention sub-block.  kv_x!=None => cross-attn."""
     B, S, _ = x.shape
     src = x if kv_x is None else kv_x
-    q = jnp.einsum("bsd,dhk->bshk", x, cast(p["wq"], rt))
-    k = jnp.einsum("bsd,dhk->bshk", src, cast(p["wk"], rt))
+    q = qk_norm(p, jnp.einsum("bsd,dhk->bshk", x, cast(p["wq"], rt)),
+                "q_norm", cfg, rt)
+    k = qk_norm(p, jnp.einsum("bsd,dhk->bshk", src, cast(p["wk"], rt)),
+                "k_norm", cfg, rt)
     v = jnp.einsum("bsd,dhk->bshk", src, cast(p["wv"], rt))
     if kv_x is None:  # self-attention gets RoPE
         q = rope(q, positions, cfg.rope_theta)
@@ -249,8 +267,10 @@ def decode_attention_block(p, x, layer_cache, pos, *, cfg, rt: TunableConfig,
     pos: scalar int32 current position.  Returns (out, updated_cache).
     """
     B = x.shape[0]
-    q = jnp.einsum("bsd,dhk->bshk", x, cast(p["wq"], rt))
-    k = jnp.einsum("bsd,dhk->bshk", x, cast(p["wk"], rt))
+    q = qk_norm(p, jnp.einsum("bsd,dhk->bshk", x, cast(p["wq"], rt)),
+                "q_norm", cfg, rt)
+    k = qk_norm(p, jnp.einsum("bsd,dhk->bshk", x, cast(p["wk"], rt)),
+                "k_norm", cfg, rt)
     v = jnp.einsum("bsd,dhk->bshk", x, cast(p["wv"], rt))
     posv = jnp.full((B, 1), pos, dtype=jnp.int32)
     q = rope(q, posv, cfg.rope_theta)

@@ -7,8 +7,13 @@ Three execution paths (chosen per workload shape, DESIGN.md §5):
     knob (spark.io.compression.codec analogue).
   * ``ep_gather`` — decode (few tokens): replicated dispatch, expert-
     sharded FFN, ``all_gather`` combine (no all-to-all for tiny T).
-  * ``dense`` — single-device smoke tests / reference: exact top-k MoE
-    with no capacity drops.
+  * ``grouped`` — no ``model`` axis above 1: every expert held here,
+    dropless; the token x top-k assignments sorted by expert and the
+    three expert products run as grouped matmuls over the sorted rows
+    (``jax.lax.ragged_dot``), so the work follows the rows routed.
+
+``_dense_moe`` (a per-slot gather of each chosen expert's weights) is
+the exact oracle the tests hold the other paths to; nothing runs it.
 """
 from __future__ import annotations
 
@@ -22,6 +27,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.params import TunableConfig
 from repro.models import layers as L
+from repro.models.scopes import scope
 
 
 def moe_spec(cfg) -> Dict[str, L.PSpec]:
@@ -35,11 +41,14 @@ def moe_spec(cfg) -> Dict[str, L.PSpec]:
 
 
 def _route(xt, router_w, cfg):
-    """xt: (T,d) -> (gate_vals (T,k) renormalized, gate_idx (T,k), aux)."""
-    logits = (xt @ router_w).astype(jnp.float32)
+    """xt: (T,d) -> (gate_vals (T,k), gate_idx (T,k), aux): the top-k of
+    the float32 softmax over all experts, renormalized to sum to one
+    where ``cfg.norm_topk_prob``."""
+    logits = jnp.dot(xt, router_w, preferred_element_type=jnp.float32)
     gates = jax.nn.softmax(logits, axis=-1)
     gv, gi = jax.lax.top_k(gates, cfg.top_k)
-    gv = gv / jnp.maximum(jnp.sum(gv, -1, keepdims=True), 1e-9)
+    if cfg.norm_topk_prob:
+        gv = gv / jnp.maximum(jnp.sum(gv, -1, keepdims=True), 1e-9)
     # load-balancing aux loss (Switch-style): E * sum_e f_e * p_e
     me = jnp.mean(gates, axis=0)
     ce = jnp.mean(
@@ -48,6 +57,20 @@ def _route(xt, router_w, cfg):
     return gv.astype(xt.dtype), gi, aux
 
 
+def _counts(gi, E):
+    """Rows routed to each of the E experts by the assignments ``gi``: a
+    compare-and-sum, which a TPU runs far faster than a scatter-add whose
+    every update lands on one of E slots."""
+    return jnp.sum(gi.reshape(-1, 1) == jnp.arange(E), 0, dtype=jnp.int32)
+
+
+def _max_load(counts):
+    """The most rows routed to one expert over the mean: 1 is an even
+    load, E / k all of them on one expert."""
+    return jnp.max(counts) / jnp.mean(counts.astype(jnp.float32))
+
+
+@scope("expert_ffn")
 def _expert_ffn(tokens, wg, wu, wd, cfg, rt):
     """tokens: (E_local, C, d); weights: (E_local, ...)."""
     if cfg.mlp_act == "silu":
@@ -98,7 +121,8 @@ def _combine(back, gv, keep, slot, flat_e, T, k, d):
 
 # ---------------------------------------------------------------- paths
 def _dense_moe(p, x, cfg, rt):
-    """Exact (no-capacity) reference path; also the 1-device path."""
+    """Exact (no-capacity) oracle: each top-k slot gathers its expert's
+    weights per token.  x: (B,S,d) -> (y, aux)."""
     B, S, d = x.shape
     xt = x.reshape(-1, d)
     gv, gi, aux = _route(xt, L.cast(p["router"], rt), cfg)
@@ -117,6 +141,40 @@ def _dense_moe(p, x, cfg, rt):
     return y, aux
 
 
+@scope("expert_ffn")
+def _grouped_ffn(rows, sizes, wg, wu, wd, cfg):
+    """The expert products over ``rows`` sorted by expert, ``sizes[e]``
+    of them routed to expert e: grouped matmuls, no padding, no drops."""
+    if cfg.mlp_act == "silu":
+        h = (jax.nn.silu(jax.lax.ragged_dot(rows, wg, sizes))
+             * jax.lax.ragged_dot(rows, wu, sizes))
+    else:
+        h = jax.nn.gelu(jax.lax.ragged_dot(rows, wu, sizes))
+    return jax.lax.ragged_dot(h, wd, sizes)
+
+
+def _grouped_moe(p, x, cfg, rt):
+    """Dropless MoE with every expert held here.  The T*k assignments are
+    sorted by expert, their rows gathered, run through the grouped expert
+    products, gathered back by the inverse permutation and summed over
+    the k slots, weighted by the gate values.  x: (B,S,d) ->
+    (y, aux, max_load)."""
+    B, S, d = x.shape
+    E, k = cfg.n_experts, cfg.top_k
+    xt = x.reshape(-1, d)
+    gv, gi, aux = _route(xt, L.cast(p["router"], rt), cfg)
+    flat = gi.reshape(-1)                                   # (T*k,)
+    order = jnp.argsort(flat, stable=True)                  # sorted -> slot
+    sizes = _counts(flat, E)
+    out = _grouped_ffn(xt[order // k], sizes, L.cast(p["wg"], rt),
+                       L.cast(p["wu"], rt), L.cast(p["wd"], rt), cfg)
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype))      # slot -> sorted
+    y = jnp.einsum("tkd,tk->td", out[inverse].reshape(-1, k, d), gv,
+                   preferred_element_type=jnp.float32)
+    return y.astype(x.dtype).reshape(B, S, d), aux, _max_load(sizes)
+
+
 def _ep_paths_applicable(cfg, rules, S):
     if rules is None:
         return None
@@ -128,11 +186,13 @@ def _ep_paths_applicable(cfg, rules, S):
     return "ep_gather"
 
 
+@scope("moe")
 def moe_mlp(p, x, cfg, rt: TunableConfig, rules):
-    """MoE FFN sub-block.  x: (B,S,d) -> (y, aux_loss)."""
+    """MoE FFN sub-block.  x: (B,S,d) -> (y, aux_loss, max_load), where
+    max_load is the most rows routed to one expert over the mean."""
     path = _ep_paths_applicable(cfg, rules, x.shape[1])
     if path is None:
-        return _dense_moe(p, x, cfg, rt)
+        return _grouped_moe(p, x, cfg, rt)
 
     mesh = rules.mesh
     ep = rules.model_axis_size()
@@ -178,7 +238,8 @@ def moe_mlp(p, x, cfg, rt: TunableConfig, rules):
             back = _decode_wire(back, bscale, comp)
             y = _combine(back, gv, keep, slot, fe, T, k, d)
             aux = jax.lax.pmean(aux, manual_axes)
-            return y.reshape(B_local, S_local, d), aux
+            load = _max_load(jax.lax.psum(_counts(gi, E), manual_axes))
+            return y.reshape(B_local, S_local, d), aux, load
 
         xspec = P(batch_axes or None, "model", None)
         f = jax.shard_map(
@@ -187,9 +248,8 @@ def moe_mlp(p, x, cfg, rt: TunableConfig, rules):
                       P("model", fsdp_in_mesh or None, None),
                       P("model", fsdp_in_mesh or None, None),
                       P("model", None, fsdp_in_mesh or None)),
-            out_specs=(xspec, P()), check_vma=False)
-        y, aux = f(x, L.cast(p["router"], rt), p["wg"], p["wu"], p["wd"])
-        return y, aux
+            out_specs=(xspec, P(), P()), check_vma=False)
+        return f(x, L.cast(p["router"], rt), p["wg"], p["wu"], p["wd"])
 
     # ep_gather: decode-time few-token path
     B_local = max(1, B // dp)
@@ -215,7 +275,8 @@ def moe_mlp(p, x, cfg, rt: TunableConfig, rules):
         back = _decode_wire(full, fscale, comp)
         y = _combine(back, gv, keep, slot, fe, T, k, d)
         aux = jax.lax.pmean(aux, manual_axes)
-        return y.reshape(B_local, S, d), aux
+        load = _max_load(jax.lax.psum(_counts(gi, E), manual_axes))
+        return y.reshape(B_local, S, d), aux, load
 
     xspec = P(batch_axes or None, None, None)
     f = jax.shard_map(
@@ -224,6 +285,5 @@ def moe_mlp(p, x, cfg, rt: TunableConfig, rules):
                   P("model", fsdp_in_mesh or None, None),
                   P("model", fsdp_in_mesh or None, None),
                   P("model", None, fsdp_in_mesh or None)),
-        out_specs=(xspec, P()), check_vma=False)
-    y, aux = f(x, L.cast(p["router"], rt), p["wg"], p["wu"], p["wd"])
-    return y, aux
+        out_specs=(xspec, P(), P()), check_vma=False)
+    return f(x, L.cast(p["router"], rt), p["wg"], p["wu"], p["wd"])

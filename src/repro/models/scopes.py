@@ -24,6 +24,10 @@ VOCABULARY = {
                  "attention (layers.attention_block, "
                  "decode_attention_block)",
     "mlp": "the dense MLP (layers.mlp_block)",
+    "moe": "the MoE layer: router, sort, gathers, expert products and "
+           "combine (moe.moe_mlp)",
+    "expert_ffn": "the expert products alone, inside moe "
+                  "(moe._grouped_ffn, moe._expert_ffn)",
     "mamba2": "the Mamba2 mixer (mamba2.mamba_block, mamba_decode_block)",
     "ssd_scan": "the chunked SSD scan, inside mamba2",
     "head": "logits and the loss (layers.unembed, layers.xent_loss)",

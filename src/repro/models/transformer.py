@@ -40,10 +40,14 @@ def spec(cfg) -> Dict:
 
 
 def _ffn(bp, h, cfg, rt, rules):
-    """FFN sub-block -> (y, aux_loss)."""
+    """FFN sub-block -> (y, the layer's counters): ``aux`` (the MoE
+    load-balancing loss, 0 for a dense MLP) and, for an MoE,
+    ``moe_max_load``."""
     if "moe" in bp:
-        return moe.moe_mlp(bp["moe"], h, cfg, rt, rules)
-    return L.mlp_block(bp["mlp"], h, cfg=cfg, rt=rt, rules=rules), 0.0
+        y, aux, load = moe.moe_mlp(bp["moe"], h, cfg, rt, rules)
+        return y, {"aux": aux, "moe_max_load": load}
+    return L.mlp_block(bp["mlp"], h, cfg=cfg, rt=rt, rules=rules), \
+        {"aux": 0.0}
 
 
 def _block(bp, x, positions, cfg, rt: TunableConfig, rules):
@@ -51,7 +55,7 @@ def _block(bp, x, positions, cfg, rt: TunableConfig, rules):
     x = x + L.attention_block(bp["attn"], h, cfg=cfg, rt=rt, rules=rules,
                               positions=positions)
     h = L.rmsnorm(x, bp["ln2"], rt, cfg.norm_eps)
-    y, aux = _ffn(bp, h, cfg, rt, rules)
+    y, counters = _ffn(bp, h, cfg, rt, rules)
     x = x + y
     if rules is not None:
         # sequence parallelism (beyond-paper): between blocks the residual
@@ -59,20 +63,24 @@ def _block(bp, x, positions, cfg, rt: TunableConfig, rules):
         # the attention boundary and the scatter after the FFN
         x = rules.constrain(x, "batch",
                             "seq_model" if rt.seq_parallel else None, None)
-    return x, aux
+    return x, counters
 
 
 def forward(p, h, positions, cfg, rt: TunableConfig, rules):
-    """h: (B,S,d) embeddings -> (final hidden states, total aux loss)."""
+    """h: (B,S,d) embeddings -> (final hidden states, counters): the aux
+    loss summed over the layers and, for an MoE, the largest layer's
+    ``moe_max_load``."""
     def body(x, bp):
         x = remat.from_carry(x, rt)
-        x, aux = _block(bp, x, positions, cfg, rt, rules)
-        return remat.to_carry(x, rt), aux
+        x, counters = _block(bp, x, positions, cfg, rt, rules)
+        return remat.to_carry(x, rt), counters
     body = remat.wrap_layer(body, rt)
-    h, auxs = scan_layers(body, remat.to_carry(h, rt), p["blocks"],
-                          unroll=rt.unroll_layers)
+    h, counters = scan_layers(body, remat.to_carry(h, rt), p["blocks"],
+                              unroll=rt.unroll_layers)
     h = remat.from_carry(h, rt)
-    return L.rmsnorm(h, p["final_norm"], rt, cfg.norm_eps), jnp.sum(auxs)
+    counters = {k: jnp.max(v) if k == "moe_max_load" else jnp.sum(v)
+                for k, v in counters.items()}
+    return L.rmsnorm(h, p["final_norm"], rt, cfg.norm_eps), counters
 
 
 def embed_inputs(p, batch, cfg, rt: TunableConfig, rules):
@@ -92,13 +100,13 @@ def loss_fn(p, batch, cfg, rt: TunableConfig, rules):
     h = embed_inputs(p, batch, cfg, rt, rules)
     B, S, _ = h.shape
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-    h, aux = forward(p, h, positions, cfg, rt, rules)
+    h, counters = forward(p, h, positions, cfg, rt, rules)
     logits = L.unembed(p["embed"], h, cfg, rt, rules)
     labels = batch["labels"]
     if labels.shape[1] < S:  # frontend positions carry no labels
         logits = logits[:, S - labels.shape[1]:]
     loss = L.xent_loss(logits, labels, cfg)
-    return loss + AUX_COEF * aux, {"xent": loss, "aux": aux}
+    return loss + AUX_COEF * counters["aux"], dict(counters, xent=loss)
 
 
 # ------------------------------------------------------------- serving
@@ -127,7 +135,8 @@ def prefill_fn(p, batch, cfg, rt: TunableConfig, rules, max_seq: int):
         # nothing of their own (PERF.md, section 5)
         k = jnp.einsum("bsd,dhk->bshk", hn, L.cast(bp["attn"]["wk"], rt))
         v = jnp.einsum("bsd,dhk->bshk", hn, L.cast(bp["attn"]["wv"], rt))
-        k = L.rope(k, positions, cfg.rope_theta)
+        k = L.rope(L.qk_norm(bp["attn"], k, "k_norm", cfg, rt), positions,
+                   cfg.rope_theta)
         x, _ = _block(bp, x, positions, cfg, rt, rules)
         kq, ks = L.quantize_kv(k, rt.kv_cache_dtype)
         vq, vs = L.quantize_kv(v, rt.kv_cache_dtype)

@@ -168,3 +168,27 @@ def test_serving_prefill_step_compiles(one_chip, mosaic):
     sched, params = _scheduler(one_chip)
     batch = {"tokens": _sds((8, 1003), "int32", one_chip)}
     _check_step(sched._prefill.lower(params, batch).compile())
+
+
+def test_olmoe_prefill_cell_compiles_within_one_chip(topo, mosaic):
+    """The step of the benchmark's cell ``olmoe-1b-7b-4l.prefill-8x4k``:
+    four OLMoE layers with all 64 experts held, bf16 weights, 8 x 4096
+    tokens, through flash attention, the RMSNorm kernel (QK-norm too) and
+    the compiler's grouped-matmul kernels of ``jax.lax.ragged_dot``."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
+
+    from bench import harness, steps
+    from repro.configs.base import ShapeConfig
+    from repro.runtime.stepfn import build_prefill_step
+    cell = harness.resolve("olmoe-1b-7b-4l.prefill-8x4k")
+    arch, rt = steps.arch_and_runtime(cell)
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    t = cell.traffic
+    bundle = build_prefill_step(arch, ShapeConfig("c", t["seq"], t["batch"],
+                                                  "prefill"), rt, mesh)
+    compiled = bundle.lower().compile()
+    text = compiled.as_text()
+    assert "ragged-dot" in text
+    _check_step(compiled)

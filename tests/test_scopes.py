@@ -49,7 +49,9 @@ def _lowered(arch, kind):
 @pytest.mark.parametrize("arch,kind", [("smollm-135m", "train"),
                                        ("smollm-135m", "prefill"),
                                        ("zamba2-7b", "train"),
-                                       ("zamba2-7b", "prefill")])
+                                       ("zamba2-7b", "prefill"),
+                                       ("olmoe-1b-7b", "train"),
+                                       ("olmoe-1b-7b", "prefill")])
 def test_every_matmul_and_custom_call_names_its_layer(arch, kind):
     text = _lowered(arch, kind).compile().as_text()
     heavy = [(n, op) for n, opcode, op in _instructions(text)
@@ -58,9 +60,13 @@ def test_every_matmul_and_custom_call_names_its_layer(arch, kind):
     unnamed = [(n, op) for n, op in heavy if _scope(op) is None]
     assert not unnamed, unnamed[:5]
     found = {_scope(op) for _, _, op in _instructions(text)} - {None}
-    want = {"embed", "norm", "attention", "mlp", "head"}
+    want = {"embed", "norm", "attention", "head"}
+    want.add("moe" if arch == "olmoe-1b-7b" else "mlp")
     if kind == "train":
         want.add("optimizer")
+    if arch == "olmoe-1b-7b":
+        assert any("/moe/" in op and "/expert_ffn/" in op
+                   for _, _, op in _instructions(text))
     if arch == "zamba2-7b":
         want.add("mamba2")
         assert any("/mamba2/" in op and "/ssd_scan/" in op
@@ -69,8 +75,9 @@ def test_every_matmul_and_custom_call_names_its_layer(arch, kind):
     if kind == "train":
         recomputed = {_scope(op) for _, _, op in _instructions(text)
                       if "rematted_computation" in op.split("/")}
-        layers = {"attention", "mlp"} if arch == "smollm-135m" \
-            else {"mamba2"}
+        layers = {"smollm-135m": {"attention", "mlp"},
+                  "olmoe-1b-7b": {"attention", "moe"},
+                  "zamba2-7b": {"mamba2"}}[arch]
         assert layers <= recomputed, recomputed
 
 

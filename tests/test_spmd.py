@@ -177,9 +177,10 @@ def test_moe_ep_alltoall_matches_dense():
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 32, cfg.d_model))
         rules = ShardingRules(mesh=mesh, strategy="fsdp_tp")
         with mesh:
-            y_ep, aux_ep = jax.jit(
+            y_ep, aux_ep, load_ep = jax.jit(
                 lambda p, xx: moe.moe_mlp(p, xx, cfg, rt, rules))(params, x)
         y_dense, aux_dense = moe._dense_moe(params, x, cfg, rt)
+        load_one = moe.moe_mlp(params, x, cfg, rt, None)[2]
         err = float(jnp.max(jnp.abs(y_ep - y_dense)))
         print("err", err, "aux", float(aux_ep), float(aux_dense))
         assert err < 1e-4, err
@@ -187,6 +188,8 @@ def test_moe_ep_alltoall_matches_dense():
         # (standard Switch-style per-device aux) — close to, but not
         # identical with, the global-batch estimator
         assert abs(float(aux_ep) - float(aux_dense)) < 0.1
+        # the load is counted over every shard's routing: the global one
+        assert abs(float(load_ep) - float(load_one)) < 1e-5
         print("MOE_OK")
     """)
     assert "MOE_OK" in out
@@ -210,7 +213,7 @@ def test_moe_ep_gather_decode_path():
         x = jax.random.normal(jax.random.PRNGKey(1), (8, 1, cfg.d_model))
         rules = ShardingRules(mesh=mesh, strategy="tp")
         with mesh:
-            y_ep, _ = jax.jit(
+            y_ep, _, _ = jax.jit(
                 lambda p, xx: moe.moe_mlp(p, xx, cfg, rt, rules))(params, x)
         y_dense, _ = moe._dense_moe(params, x, cfg, rt)
         err = float(jnp.max(jnp.abs(y_ep - y_dense)))
