@@ -20,6 +20,8 @@ import dataclasses
 import re
 from typing import Dict, List, Optional, Tuple
 
+from repro.models.layers import query_chunks
+
 HW = {
     "flops_bf16": 197e12,
     "flops_f32": 98.5e12,
@@ -289,26 +291,43 @@ def flash_memory_correction_bytes(cfg, shape, rt, data_size: int,
     return total / shards
 
 
+def xla_score_pairs(sq: int, skv: int) -> int:
+    """Query x key pairs the XLA attention (``layers.full_attention``)
+    scores for one (batch, head): the staircase of its query chunks when
+    the application is square (causal self-attention), else the whole
+    rectangle."""
+    chunks = query_chunks(sq, skv, causal=sq == skv)
+    if len(chunks) == 1:
+        return sq * skv
+    return sum((e - s) * e for s, e in chunks)
+
+
 def flash_peak_correction_bytes(cfg, shape, rt, data_size: int,
                                 model_size: int) -> float:
-    """Per-chip PEAK bytes removed by the flash kernel: the stored
-    (B,H,Sq,Skv) softmax tensors (x2: pre-softmax scores + probabilities).
-    With remat 'none'/'dots' (dots_saveable keeps dot outputs) every
-    layer's scores are live for the backward; with 'full' (or
-    forward-only steps) only ~2 transient layers are."""
+    """Per-chip PEAK bytes removed by the flash kernel: the f32 score and
+    softmax tensors the XLA attention holds over the pairs it scores
+    (``xla_score_pairs``).  With remat 'none'/'dots' (dots_saveable keeps
+    dot outputs) every layer's are live for the backward; with 'full'
+    (or forward-only steps) only the layer in flight's are."""
     if rt.attn_impl != "pallas":
         return 0.0
     B, H = shape.global_batch, cfg.n_heads
     shards = attention_shards(cfg, rt, data_size, model_size)
     stored_all = shape.kind == "train" and rt.remat_policy in ("none",
                                                                "dots")
-    # ~3 (B,H,Sq,Skv) f32 tensors live per layer on the XLA path (raw
-    # scores, masked scores, softmax out — measured per-layer delta on
-    # the scanned compile is ~2.7 of them)
+    # f32 bytes held per scored pair, measured on the calibrated XLA peak
+    # of smollm-135m (reduced, where the scores are nearly all of the
+    # peak) at 4 x 2048 on the CPU: ~3.3 (whole square) to ~4 (query
+    # chunks) per layer when the backward keeps them; ~2 (forward) to
+    # ~3.3-4.5 (forward + backward) for the one layer in flight
+    # otherwise.  The factors take the low end: a correction that removes
+    # more than the XLA program holds makes the fit check pass
+    # configurations that do not fit.
+    per_pair = 3.0 if stored_all else 2.0
     total = 0.0
     for count, sq, skv in attention_applications(cfg, shape):
-        live = count if stored_all else min(count, 2)
-        total += live * 3.0 * B * H * sq * skv * 4.0
+        live = count if stored_all else min(count, 1)
+        total += live * per_pair * B * H * xla_score_pairs(sq, skv) * 4.0
     return total / shards
 
 

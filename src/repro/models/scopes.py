@@ -23,6 +23,8 @@ VOCABULARY = {
     "attention": "q/k/v/o projections, RoPE and the XLA or Pallas "
                  "attention (layers.attention_block, "
                  "decode_attention_block)",
+    "attention_scores": "the XLA attention's scores, softmax and weighted "
+                        "values (layers.full_attention), inside attention",
     "mlp": "the dense MLP (layers.mlp_block)",
     "moe": "the MoE layer: router, sort, gathers, expert products and "
            "combine (moe.moe_mlp)",

@@ -133,20 +133,27 @@ def test_int8_kv_cache_close_to_bf16(key):
     assert err < 0.5, f"int8 kv cache diverges: {err}"
 
 
-@pytest.mark.parametrize("dtype,atol", [("float32", 2e-5),
-                                        ("bfloat16", 3e-2)])
-def test_attention_block_pallas_matches_xla(key, monkeypatch, dtype, atol):
+@pytest.mark.parametrize("dtype,atol,seq", [
+    pytest.param("float32", 2e-5, 256, id="float32-2e-05"),
+    pytest.param("bfloat16", 3e-2, 256, id="bfloat16-0.03"),
+    pytest.param("float32", 2e-5, 1024, id="float32-2e-05-chunked"),
+    pytest.param("bfloat16", 3e-2, 1024, id="bfloat16-0.03-chunked")])
+def test_attention_block_pallas_matches_xla(key, monkeypatch, dtype, atol,
+                                            seq):
     """Causal attention through the flash kernel, which takes K/V at the
     KV heads, == the XLA path, which takes them repeated to the query
-    heads, on a GQA config (9 query heads over 3 KV heads)."""
+    heads, on a GQA config (9 query heads over 3 KV heads); at 1024 the
+    XLA path computes its queries in chunks (``layers.query_chunks``)."""
     import dataclasses
     from repro.kernels.flash_attention import ops as fa_ops
     from repro.models import layers as L
     cfg = dataclasses.replace(get_reduced("smollm-135m"), n_heads=9,
                               n_kv_heads=3, head_dim=32)
     params = L.init_params(L.attn_spec(cfg), key)
-    x = jax.random.normal(jax.random.PRNGKey(1), (2, 256, cfg.d_model))
-    positions = jnp.broadcast_to(jnp.arange(256), (2, 256))
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, seq, cfg.d_model))
+    positions = jnp.broadcast_to(jnp.arange(seq), (2, seq))
+    chunks = L.query_chunks(seq, seq, causal=True)
+    assert (len(chunks) > 1) == (seq > 256)
     kv_heads = {}
 
     def spy(impl, fn):
