@@ -58,12 +58,13 @@ def _baseline(spec=None):
 def _evaluator(scratch, slo_ttft=None):
     """The dispatch stack over a bench-local timing cache (the shared
     results/trials cache must not leak arm-to-arm state in here)."""
-    from repro.core.kernel_cell import DispatchEvaluator
+    from repro.core.cells import DispatchEvaluator, default_evaluator
     from repro.core.measure import TimingCache
     from repro.serving.evaluator import make_serve_evaluator
     serve = make_serve_evaluator(
         slo_ttft=slo_ttft, cache=TimingCache(scratch / "timings"))
-    return DispatchEvaluator(serve=serve, slo_ttft=slo_ttft)
+    return DispatchEvaluator({**default_evaluator(slo_ttft).evaluators,
+                              "serve": serve})
 
 
 def run_bad_config(cells, scratch):

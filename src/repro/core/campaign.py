@@ -57,6 +57,9 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 from repro.configs import SHAPES, get_config, get_shape, list_archs, \
     shape_applicable
 from repro.core import telemetry as _telemetry
+from repro.core.cells import (base_name, default_evaluator,
+                              default_measured_evaluator, kind_of,
+                              parse_cell)
 from repro.core.executor import SweepExecutor
 from repro.core.fsutil import atomic_publish
 from repro.core.history import (HISTORY_FILENAME, TrialHistory,
@@ -75,20 +78,25 @@ CHECKPOINT_VERSION = 2
 # ---------------------------------------------------------------- cells
 @dataclasses.dataclass(frozen=True)
 class CellSpec:
-    """One (arch, shape, mesh) cell of the assignment matrix."""
+    """One (arch, shape, mesh) cell of the assignment matrix, of the
+    kind its arch names (core/cells.py)."""
     arch: str
     shape: str
     multi_pod: bool = False
 
     def workload(self) -> Workload:
-        return Workload(self.arch, self.shape, self.multi_pod)
+        return kind_of(self.arch).workload(self.arch, self.shape,
+                                           self.multi_pod)
 
     def key(self) -> str:
         return self.workload().key()
 
     def spec(self) -> str:
-        """The ``arch:shape:mesh`` string :func:`parse_cells` accepts
-        (the fabric coordinator rebuilds worker command lines from it)."""
+        """The string :func:`parse_cells` accepts (the fabric
+        coordinator rebuilds worker command lines from it)."""
+        head = kind_of(self.arch).head
+        if head:
+            return f"{head}:{base_name(self.arch)}:{self.shape}"
         return f"{self.arch}:{self.shape}:" \
             + ("multipod" if self.multi_pod else "pod")
 
@@ -109,43 +117,37 @@ def enumerate_cells(archs: Optional[Sequence[str]] = None,
     return out
 
 
+def parse_step_cell(item: str, default_multi_pod: bool = False
+                    ) -> CellSpec:
+    """Parse one ``arch:shape[:pod|multipod]`` step-cell spec; without
+    a mesh suffix the cell takes ``default_multi_pod``."""
+    parts = item.strip().split(":")
+    if len(parts) not in (2, 3):
+        raise ValueError(f"bad cell spec {item!r} "
+                         "(want arch:shape[:pod|multipod])")
+    arch, shape = parts[0], parts[1]
+    mp = default_multi_pod
+    if len(parts) == 3:
+        if parts[2] not in ("pod", "multipod"):
+            raise ValueError(f"bad mesh {parts[2]!r} in {item!r}")
+        mp = parts[2] == "multipod"
+    cfg = get_config(arch)                # raises on unknown arch
+    shp = get_shape(shape)                # raises on unknown shape
+    ok, reason = shape_applicable(cfg, shp)
+    if not ok:
+        raise ValueError(f"cell {item!r} not applicable: {reason}")
+    return CellSpec(arch, shape, mp)
+
+
 def parse_cells(text: str,
                 default_multi_pod: bool = False) -> List[CellSpec]:
-    """Parse ``arch:shape[:pod|multipod]`` comma-separated cell specs;
-    specs without an explicit mesh suffix use ``default_multi_pod``.
-    ``kernel:<name>:<shape>`` specs become
-    :class:`~repro.core.kernel_cell.KernelCell` s (Pallas tile-sweep
-    cells), so every cell entry point — ``--cells``, ``--add-cells``
-    intake, fabric worker command lines — accepts them."""
-    cells = []
-    for item in text.split(","):
-        item = item.strip()
-        if not item:
-            continue
-        parts = item.split(":")
-        if parts[0] == "kernel":
-            from repro.core.kernel_cell import parse_kernel_cell
-            cells.append(parse_kernel_cell(item))
-            continue
-        if parts[0] == "serve":
-            from repro.serving.evaluator import parse_serve_cell
-            cells.append(parse_serve_cell(item))
-            continue
-        if len(parts) not in (2, 3):
-            raise ValueError(f"bad cell spec {item!r} "
-                             "(want arch:shape[:pod|multipod])")
-        arch, shape = parts[0], parts[1]
-        mp = default_multi_pod
-        if len(parts) == 3:
-            if parts[2] not in ("pod", "multipod"):
-                raise ValueError(f"bad mesh {parts[2]!r} in {item!r}")
-            mp = parts[2] == "multipod"
-        cfg = get_config(arch)            # raises on unknown arch
-        shp = get_shape(shape)            # raises on unknown shape
-        ok, reason = shape_applicable(cfg, shp)
-        if not ok:
-            raise ValueError(f"cell {item!r} not applicable: {reason}")
-        cells.append(CellSpec(arch, shape, mp))
+    """Parse comma-separated cell specs of any kind (core/cells.py:
+    ``arch:shape[:pod|multipod]``, ``kernel:<name>:<shape>``,
+    ``serve:<arch>:<trace>``), so every cell entry point — ``--cells``,
+    ``--add-cells`` intake, fabric worker command lines — accepts them
+    all.  Step specs without a mesh suffix use ``default_multi_pod``."""
+    cells = [parse_cell(item.strip(), default_multi_pod)
+             for item in text.split(",") if item.strip()]
     if not cells:
         raise ValueError("no cells in spec")
     return cells
@@ -207,18 +209,11 @@ def cell_health(log) -> Dict:
 
 
 def _default_stages(spec: CellSpec) -> Optional[List[Stage]]:
-    """The campaign's default stages factory: kernel cells walk their
-    tile-sweep stage (core/kernel_cell.py), serve cells their serving
-    tree (serving/evaluator.py); step cells return None so the strategy
-    keeps its own default tree — bit-identical to the historical
+    """The campaign's default stages factory: the cell kind's stage
+    tree (core/cells.py); step cells return None so the strategy keeps
+    its own default tree — bit-identical to the historical
     ``lambda spec: None``."""
-    if str(spec.arch).startswith("kernel-"):
-        from repro.core.kernel_cell import kernel_stages
-        return kernel_stages(spec)
-    if str(spec.arch).startswith("serve-"):
-        from repro.serving.evaluator import serve_stages
-        return serve_stages(spec)
-    return None
+    return kind_of(spec.arch).stages(spec)
 
 
 # ------------------------------------------------------------- campaign
@@ -337,11 +332,9 @@ class Campaign:
         if executor is not None:
             evaluator = executor.evaluator
         elif evaluator is None:
-            # kernel-aware default: kernel cells time their jitted
-            # kernel, everything else passes through to the same
-            # RooflineEvaluator as before (bit-identical step decisions)
-            from repro.core.kernel_cell import DispatchEvaluator
-            evaluator = DispatchEvaluator()
+            # step decisions are bit-identical to a bare
+            # RooflineEvaluator's
+            evaluator = default_evaluator()
         self.evaluator = evaluator
         self.executor = executor
         self.max_workers = max_workers
@@ -649,13 +642,13 @@ class Campaign:
     # ----------------------------------------------------- measured tier
     def _resolve_measured_evaluator(self) -> Callable:
         """The evaluator the re-rank pass times configs with: the
-        injected one, else the measured tier's default (kernel bench /
-        reduced wall-clock proxy behind the disk timing cache)."""
+        injected one, else the measured tier's default (core/cells.py:
+        kernel bench / reduced wall-clock proxy / trace replay behind
+        the disk timing cache)."""
         if self._measured_eval is None:
             if self.measured_evaluator is not None:
                 self._measured_eval = self.measured_evaluator
             else:
-                from repro.core.measure import default_measured_evaluator
                 self._measured_eval = default_measured_evaluator()
         return self._measured_eval
 

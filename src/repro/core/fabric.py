@@ -92,7 +92,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 
 from repro.core import telemetry as _telemetry
 from repro.core.campaign import (CHECKPOINT_VERSION, Campaign, CellSpec)
-from repro.core.executor import SweepExecutor
+from repro.core.cells import default_evaluator
 from repro.core.fsutil import atomic_publish
 from repro.core.history import HISTORY_FILENAME, TrialHistory
 from repro.core.strategy import get_strategy
@@ -384,12 +384,11 @@ class Heartbeat:
 # --------------------------------------------------------------- worker
 def load_evaluator(spec: Optional[str]) -> Callable:
     """Resolve an ``--evaluator module:factory`` dotted-path spec (the
-    factory is called with no arguments); default: the kernel-aware
-    :class:`~repro.core.kernel_cell.DispatchEvaluator` (bit-identical
-    to a bare RooflineEvaluator on step cells)."""
+    factory is called with no arguments); default: the trial tier's
+    :func:`~repro.core.cells.default_evaluator` (bit-identical to a
+    bare RooflineEvaluator on step cells)."""
     if not spec:
-        from repro.core.kernel_cell import DispatchEvaluator
-        return DispatchEvaluator()
+        return default_evaluator()
     mod, sep, attr = spec.partition(":")
     if not sep or not attr:
         raise ValueError(f"evaluator spec {spec!r}: want module:factory")
@@ -423,11 +422,11 @@ class FabricWorker:
 
     Start any number of workers over the same ``directory`` — locally
     via :func:`run_coordinator`, or independently on other hosts
-    against a shared mount.  ``evaluator`` defaults to the
-    kernel-aware :class:`~repro.core.kernel_cell.DispatchEvaluator`
-    (a RooflineEvaluator on step cells, the timing-cached kernel bench
-    on kernel cells) whose disk caches are shared with every other
-    worker.
+    against a shared mount.  ``evaluator`` defaults to the trial
+    tier's :func:`~repro.core.cells.default_evaluator` (a
+    RooflineEvaluator on step cells, timing-cached kernel bench and
+    trace replay on kernel and serve cells) whose disk caches are
+    shared with every other worker.
 
     **Online mode** (core/schedule.py) — target cells are not frozen at
     startup: every scheduling pass re-scans the shared directory's
@@ -482,10 +481,9 @@ class FabricWorker:
         self.strategy_options = dict(strategy_options or {})
         self.threshold = threshold
         if evaluator is None:
-            # kernel-aware default, like Campaign's: step decisions
-            # stay bit-identical to a bare RooflineEvaluator
-            from repro.core.kernel_cell import DispatchEvaluator
-            evaluator = DispatchEvaluator()
+            # Campaign's default: step decisions stay bit-identical to
+            # a bare RooflineEvaluator
+            evaluator = default_evaluator()
         self.evaluator = evaluator
         self.baseline_factory = baseline_factory
         self.board = LeaseBoard(self.dir, worker_id=worker_id,

@@ -60,6 +60,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.core.cells import kind_of
 from repro.core.fsutil import append_jsonl
 from repro.core.params import TunableConfig
 from repro.core.space import SPACE
@@ -104,18 +105,16 @@ def active_knobs(kind: str, family: str) -> Tuple[str, ...]:
 
 
 def cell_signature(arch: str, shape: str, multi_pod: bool = False) -> Dict:
-    """The features warm-start similarity is computed over."""
-    if arch.startswith("kernel-"):
-        # kernel cells (core/kernel_cell.py) have no arch config /
-        # SHAPES entry; their signature comes from the kernel registry
-        # so history prioritization and warm-start never crash on them
-        from repro.core.kernel_cell import kernel_signature
-        return kernel_signature(arch, shape, multi_pod)
-    if arch.startswith("serve-"):
-        # serve cells (serving/evaluator.py): trace name is the shape,
-        # the serving knob subset is the active-knob list
-        from repro.serving.evaluator import serve_signature
-        return serve_signature(arch, shape, multi_pod)
+    """The features warm-start similarity is computed over, by the
+    cell's kind (core/cells.py): kernel and serve cells have no arch
+    config or SHAPES entry, so their signatures come from the kernel
+    registry and the serving knob subset."""
+    return kind_of(arch).signature(arch, shape, multi_pod)
+
+
+def step_signature(arch: str, shape: str, multi_pod: bool = False) -> Dict:
+    """A step cell's signature: its shape kind, arch family and active
+    knob set."""
     from repro.configs import get_config, get_shape
     kind = get_shape(shape).kind
     family = get_config(arch).family

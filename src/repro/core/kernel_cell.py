@@ -20,9 +20,10 @@ Design decisions:
     rmsnorm's row block), so quarantine config keys, history records
     and every strategy work without a second config type;
   * a kernel cell is a :class:`~repro.core.campaign.CellSpec` whose
-    ``arch`` is ``kernel-<name>`` and whose shapes come from the
-    :data:`KERNELS` registry — cell keys stay three ``__``-separated
-    parts, checkpoints/leases/reports all behave identically;
+    ``arch`` is ``kernel-<name>`` (core/cells.py) and whose shapes
+    come from the :data:`KERNELS` registry — cell keys stay three
+    ``__``-separated parts, checkpoints/leases/reports all behave
+    identically;
   * a tile that does not divide the shape's sequence length is a
     **clean deterministic-crash trial** (validated up front via
     ``Knob.validate_tile``), exactly like the paper's failed 0.1/0.7
@@ -34,25 +35,19 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Tuple
 
 import jax
 import numpy as np
 
 from repro.configs.base import ShapeConfig
 from repro.core.campaign import CellSpec
+from repro.core.cells import base_name, kind, kind_of
 from repro.core.params import TunableConfig
 from repro.core.space import SPACE
 from repro.core.tree import Stage
 from repro.core.trial import (TrialError, TrialResult, Workload,
                               classify_exception)
-
-KERNEL_ARCH_PREFIX = "kernel-"
-
-
-def is_kernel_workload(wl: Any) -> bool:
-    return str(getattr(wl, "arch", "")).startswith(KERNEL_ARCH_PREFIX)
-
 
 # ------------------------------------------------------------- registry
 @dataclasses.dataclass(frozen=True)
@@ -185,38 +180,18 @@ class KernelWorkload(Workload):
     :class:`KernelBenchEvaluator`)."""
 
     @property
-    def kernel(self) -> str:
-        return self.arch[len(KERNEL_ARCH_PREFIX):]
-
-    @property
     def cfg(self):
         raise TrialError(f"kernel workload {self.key()} has no arch "
                          "config — route it to KernelBenchEvaluator")
 
     @property
     def shp(self) -> ShapeConfig:
-        ks = KERNELS[self.kernel].shapes[self.shape]
+        ks = KERNELS[base_name(self.arch)].shapes[self.shape]
         return ShapeConfig(self.shape, ks.seq_len, 1, "kernel")
 
 
-@dataclasses.dataclass(frozen=True)
-class KernelCell(CellSpec):
-    """One (kernel, shape) tile-sweep cell.  ``arch`` is
-    ``kernel-<name>`` so cell keys/checkpoints/leases keep the
-    three-part ``arch__shape__mesh`` layout everywhere."""
-
-    @property
-    def kernel(self) -> str:
-        return self.arch[len(KERNEL_ARCH_PREFIX):]
-
-    def workload(self) -> KernelWorkload:
-        return KernelWorkload(self.arch, self.shape, self.multi_pod)
-
-    def spec(self) -> str:
-        return f"kernel:{self.kernel}:{self.shape}"
-
-
-def kernel_cell(kernel: str, shape: str) -> KernelCell:
+def kernel_cell(kernel: str, shape: str) -> CellSpec:
+    """One (kernel, shape) tile-sweep cell, arch ``kernel-<name>``."""
     if kernel not in KERNELS:
         raise ValueError(f"unknown kernel {kernel!r} "
                          f"(known: {', '.join(sorted(KERNELS))})")
@@ -224,32 +199,14 @@ def kernel_cell(kernel: str, shape: str) -> KernelCell:
         raise ValueError(
             f"unknown shape {shape!r} for kernel {kernel!r} "
             f"(known: {', '.join(sorted(KERNELS[kernel].shapes))})")
-    return KernelCell(KERNEL_ARCH_PREFIX + kernel, shape, False)
-
-
-def parse_kernel_cell(item: str) -> KernelCell:
-    """Parse one ``kernel:<name>:<shape>`` cell spec (the string
-    :meth:`KernelCell.spec` emits and the fabric round-trips)."""
-    parts = item.strip().split(":")
-    if len(parts) != 3 or parts[0] != "kernel":
-        raise ValueError(f"bad kernel cell spec {item!r} "
-                         "(want kernel:<name>:<shape>)")
-    return kernel_cell(parts[1], parts[2])
-
-
-def kernel_cells(kernels: Optional[List[str]] = None) -> List[KernelCell]:
-    """Every registered (kernel, shape) cell."""
-    return [kernel_cell(k, s)
-            for k in (kernels or sorted(KERNELS))
-            for s in sorted(KERNELS[k].shapes)]
+    return CellSpec(kind("kernel").prefix + kernel, shape, False)
 
 
 def kernel_signature(arch: str, shape: str, multi_pod: bool = False
                      ) -> Dict:
     """Warm-start similarity features for a kernel cell (the kernel-side
     counterpart of :func:`repro.core.history.cell_signature`)."""
-    name = arch[len(KERNEL_ARCH_PREFIX):]
-    ks = KERNELS.get(name)
+    ks = KERNELS.get(base_name(arch))
     return {
         "arch": arch,
         "shape": shape,
@@ -267,7 +224,7 @@ def kernel_stages(spec: Any) -> List[Stage]:
     kernel's tile projection (≤ 8 + baseline — inside the paper's
     ≤ 10-trial budget)."""
     import itertools
-    ks = KERNELS[spec.arch[len(KERNEL_ARCH_PREFIX):]]
+    ks = KERNELS[base_name(spec.arch)]
     defaults = {n: SPACE[n].default for n in ks.knobs}
     alts = []
     for combo in itertools.product(*(SPACE[n].domain for n in ks.knobs)):
@@ -295,10 +252,9 @@ class KernelBenchEvaluator:
     def __call__(self, wl: Workload, rt: TunableConfig) -> TrialResult:
         t0 = time.time()
         try:
-            if not is_kernel_workload(wl):
+            if kind_of(wl.arch).name != "kernel":
                 raise TrialError(f"{wl.key()} is not a kernel cell")
-            name = wl.arch[len(KERNEL_ARCH_PREFIX):]
-            ks = KERNELS.get(name)
+            ks = KERNELS.get(base_name(wl.arch))
             if ks is None or wl.shape not in ks.shapes:
                 raise TrialError(f"unknown kernel cell {wl.key()}")
             shape = ks.shapes[wl.shape]
@@ -325,47 +281,3 @@ class KernelBenchEvaluator:
                                error=err[:500],
                                failure=classify_exception(e),
                                compile_s=round(time.time() - t0, 2))
-
-
-class DispatchEvaluator:
-    """The campaign's cell-kind-aware default evaluator: kernel
-    workloads go to the (timing-cached) kernel bench, serve workloads
-    to the (timing-cached) traffic-replay evaluator
-    (serving/evaluator.py, lazily built so pure-step campaigns never
-    import the serving stack), every other workload passes through to
-    the step evaluator unchanged — a pure-step campaign's decisions
-    are bit-identical to a bare RooflineEvaluator's."""
-
-    def __init__(self, step: Optional[Callable] = None,
-                 kernel: Optional[Callable] = None,
-                 serve: Optional[Callable] = None,
-                 slo_ttft: Optional[float] = None):
-        if step is None:
-            from repro.core.trial import RooflineEvaluator
-            step = RooflineEvaluator()
-        if kernel is None:
-            from repro.core.measure import CachedMeasure
-            kernel = CachedMeasure(KernelBenchEvaluator())
-        self.step = step
-        self.kernel = kernel
-        self.serve = serve
-        self.slo_ttft = slo_ttft
-
-    def _serve_eval(self) -> Callable:
-        if self.serve is None:
-            from repro.serving.evaluator import make_serve_evaluator
-            self.serve = make_serve_evaluator(slo_ttft=self.slo_ttft)
-        return self.serve
-
-    def __call__(self, wl: Workload, rt: TunableConfig) -> TrialResult:
-        if is_kernel_workload(wl):
-            return self.kernel(wl, rt)
-        if str(getattr(wl, "arch", "")).startswith("serve-"):
-            return self._serve_eval()(wl, rt)
-        return self.step(wl, rt)
-
-
-def make_evaluator() -> DispatchEvaluator:
-    """Zero-arg factory (``--evaluator repro.core.kernel_cell:
-    make_evaluator`` — also what the campaign builds by default)."""
-    return DispatchEvaluator()

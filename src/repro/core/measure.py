@@ -26,14 +26,15 @@ Three pieces:
     memoized deterministic crash is re-raised with its stored failure
     class (pre-tagged, so :func:`~repro.core.trial.classify_exception`
     keeps it).
-  * :func:`select_top_k` / :func:`default_measured_evaluator` — the
-    re-rank candidate selection over a cell's trial log, and the
-    measured tier's default evaluator: kernel cells time their jitted
-    kernel (core/kernel_cell.py, interpret mode on CPU), step cells
-    time the *reduced runnable proxy* of their step on a single-device
-    host mesh, which times the CPU and not the chip (on a chip host pass
-    a :class:`~repro.core.trial.WallClockEvaluator` over a mesh of the
+  * :func:`select_top_k` / :class:`ReducedWallClock` — the re-rank
+    candidate selection over a cell's trial log, and the step cells'
+    measured evaluator: it times the *reduced runnable proxy* of the
+    step on a single-device host mesh, which times the CPU and not the
+    chip (on a chip host pass a
+    :class:`~repro.core.trial.WallClockEvaluator` over a mesh of the
     host's own chips as ``Campaign(measured_evaluator=...)`` instead).
+    The measured tier's default, one evaluator per cell kind behind
+    this cache, is :func:`repro.core.cells.default_measured_evaluator`.
 """
 from __future__ import annotations
 
@@ -229,31 +230,3 @@ class ReducedWallClock:
             # forward-only prefill/decode kinds keep the real kernel
             rt = rt.replace(attn_impl="xla")
         return self._ev(proxy, rt)
-
-
-def default_measured_evaluator(cache_dir: Optional[pathlib.Path] = None,
-                               repeats: int = 3) -> CachedMeasure:
-    """The campaign's measured tier when none is injected: dispatch
-    kernel cells to the kernel bench, step cells to the reduced
-    wall-clock proxy; wrap everything in the disk-backed timing cache
-    (``cache_dir`` defaults to the shared ``results/trials/timings``)."""
-    from repro.core.kernel_cell import (KernelBenchEvaluator,
-                                        is_kernel_workload)
-    step = ReducedWallClock(repeats=repeats)
-    kern = KernelBenchEvaluator(repeats=repeats)
-
-    def dispatch(wl: Workload, rt: TunableConfig) -> TrialResult:
-        if is_kernel_workload(wl):
-            return kern(wl, rt)
-        if str(getattr(wl, "arch", "")).startswith("serve-"):
-            # serve cells are *already* measured (the trial cost is a
-            # trace replay): the re-rank pass replays the same trace,
-            # guard off, through its own lazily-built evaluator
-            from repro.serving.evaluator import ServeEvaluator
-            if not hasattr(dispatch, "_serve"):
-                dispatch._serve = ServeEvaluator()
-            return dispatch._serve(wl, rt)
-        return step(wl, rt)
-
-    return CachedMeasure(dispatch, cache=TimingCache(cache_dir),
-                         repeats=repeats)

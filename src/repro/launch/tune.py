@@ -119,6 +119,7 @@ import pathlib
 import sys
 
 from repro.core import report
+from repro.core.cells import default_evaluator
 from repro.core.params import SENSITIVITY_SWEEP, default_config
 from repro.core.trial import RooflineEvaluator, TrialRunner, Workload
 from repro.launch import chip
@@ -280,11 +281,10 @@ def tune_campaign(cells, threshold: float = 0.05, baseline_overrides=None,
         from repro.core import telemetry as _telemetry
         ckpt.mkdir(parents=True, exist_ok=True)
         _telemetry.install(_telemetry.Telemetry(ckpt))
-    if evaluator is None and slo_ttft is not None:
-        # the default dispatch stack, with the serve tier's SLO guard
-        # armed — step/kernel cells are routed exactly as before
-        from repro.core.kernel_cell import DispatchEvaluator
-        evaluator = DispatchEvaluator(slo_ttft=slo_ttft)
+    if evaluator is None:
+        # the trial tier's default, with the serve cells' SLO guard
+        # armed when --slo-ttft is given
+        evaluator = default_evaluator(slo_ttft)
     camp = Campaign(
         cells, strategy=strategy, strategy_options=strategy_options,
         threshold=threshold, checkpoint_dir=ckpt, evaluator=evaluator,
@@ -320,18 +320,11 @@ def run_worker(args, cells, options) -> int:
     """``--worker``: one fabric worker over a shared directory."""
     from repro.core.fabric import FabricWorker, load_evaluator
     ckpt = campaign_dir(args.strategy, args.dir)
-    if args.evaluator:
-        evaluator = load_evaluator(args.evaluator)
-    elif args.slo_ttft is not None:
-        # default dispatch stack with the serve tier's SLO guard armed
-        from repro.core.kernel_cell import DispatchEvaluator
-        evaluator = DispatchEvaluator(slo_ttft=args.slo_ttft)
-    else:
-        evaluator = load_evaluator(None)
     worker = FabricWorker(
         cells, ckpt, strategy=args.strategy, strategy_options=options,
         threshold=args.threshold,
-        evaluator=evaluator,
+        evaluator=load_evaluator(args.evaluator) if args.evaluator
+        else default_evaluator(args.slo_ttft),
         baseline_factory=lambda spec: _baseline(),
         worker_id=args.worker_id, ttl_s=args.worker_ttl,
         warm_start=args.warm_start,
@@ -344,8 +337,7 @@ def run_worker(args, cells, options) -> int:
         max_retries=args.max_retries,
         strike_threshold=args.strike_threshold,
         measure_top_k=args.measure_top_k,
-        measured_evaluator=load_evaluator(args.measured_evaluator)
-        if args.measured_evaluator else None,
+        measured_evaluator=_load_measured(args),
         promote=args.promote, trace=args.trace)
     stats = worker.run()
     print(json.dumps(stats, indent=1))
@@ -614,7 +606,8 @@ def main(argv=None) -> int:
                           "per-strategy campaign checkpoint dir)")
     fab.add_argument("--evaluator",
                      help="module:factory dotted path for the trial "
-                          "evaluator (default: RooflineEvaluator; "
+                          "evaluator (default: the cell-kind dispatcher, "
+                          "core/cells.default_evaluator; "
                           "benchmarks/tests swap in synthetic surfaces)")
     fab.add_argument("--worker-ttl", type=float, default=30.0,
                      help="lease TTL seconds: a lease whose heartbeat "

@@ -33,6 +33,7 @@ import time
 from typing import Any, Dict, List, Optional
 
 from repro.core import telemetry as _telemetry
+from repro.core.cells import kind_of
 from repro.core.fsutil import append_jsonl, atomic_publish
 from repro.core.trial import FAILURE_DETERMINISTIC, TrialError
 
@@ -194,11 +195,10 @@ def promote_winners(directory: pathlib.Path, reports: Dict[str, Any],
     reports (cell key -> TuningReport).  Crashed finals (cost inf/nan)
     never promote; the measured-tier winner overrides the model winner
     when attached.  Returns the history records written."""
-    from repro.serving.evaluator import SERVE_ARCH_PREFIX
     board = PromotionBoard(directory)
     out = []
     for key, rep in sorted(reports.items()):
-        if not key.startswith(SERVE_ARCH_PREFIX):
+        if kind_of(key).name != "serve":
             continue
         config = dict(rep.final_config)
         cost = float(rep.final_cost)

@@ -9,9 +9,9 @@ scores a scalar cost from TTFT / decode throughput / p95 queue delay —
 the campaign / strategy / fabric / quarantine / measured-tier machinery
 runs unchanged on top.
 
-Structure mirrors core/kernel_cell.py (the new-cell-kind template):
+Structure mirrors core/kernel_cell.py; core/cells.py binds both kinds:
 
-  * :class:`ServeCell` is a :class:`~repro.core.campaign.CellSpec` whose
+  * a serve cell is a :class:`~repro.core.campaign.CellSpec` whose
     ``arch`` is ``serve-<arch>`` and whose shape is the trace name, so
     cell keys stay three ``__``-separated parts and every checkpoint /
     lease / report path behaves identically;
@@ -41,21 +41,20 @@ from __future__ import annotations
 import collections
 import dataclasses
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 
 from repro.configs.base import ShapeConfig
 from repro.core.campaign import CellSpec
+from repro.core.cells import base_name, kind, kind_of
 from repro.core.measure import CachedMeasure, TimingCache, measure_key
 from repro.core.params import TunableConfig, default_config
 from repro.core.space import SPACE
 from repro.core.tree import Stage
-from repro.core.trial import (FAILURE_DETERMINISTIC, TrialError,
-                              TrialResult, Workload, classify_exception)
+from repro.core.trial import (TrialError, TrialResult, Workload,
+                              classify_exception)
 from repro.serving.traffic import Trace, get_trace, request_tokens
-
-SERVE_ARCH_PREFIX = "serve-"
 
 #: bump when the replay protocol / cost formula changes (invalidates
 #: cached trace costs)
@@ -67,10 +66,6 @@ SERVE_MEASURE_VERSION = "serve-v1"
 W_TTFT, W_P95_QDELAY, W_DECODE = 1.0, 0.5, 1.0
 
 
-def is_serve_workload(wl: Any) -> bool:
-    return str(getattr(wl, "arch", "")).startswith(SERVE_ARCH_PREFIX)
-
-
 # ---------------------------------------------------------------- cells
 @dataclasses.dataclass
 class ServeWorkload(Workload):
@@ -79,13 +74,9 @@ class ServeWorkload(Workload):
     on CPU in CI) and ``shp`` is derived from the trace geometry."""
 
     @property
-    def base_arch(self) -> str:
-        return self.arch[len(SERVE_ARCH_PREFIX):]
-
-    @property
     def cfg(self):
         from repro.configs import get_reduced
-        return get_reduced(self.base_arch)
+        return get_reduced(base_name(self.arch))
 
     @property
     def shp(self) -> ShapeConfig:
@@ -94,23 +85,8 @@ class ServeWorkload(Workload):
         return ShapeConfig(self.shape, seq, len(tr.requests), "serve")
 
 
-@dataclasses.dataclass(frozen=True)
-class ServeCell(CellSpec):
-    """One (arch, trace) serving cell.  ``arch`` is ``serve-<arch>`` so
-    cell keys keep the three-part ``arch__shape__mesh`` layout."""
-
-    @property
-    def base_arch(self) -> str:
-        return self.arch[len(SERVE_ARCH_PREFIX):]
-
-    def workload(self) -> ServeWorkload:
-        return ServeWorkload(self.arch, self.shape, self.multi_pod)
-
-    def spec(self) -> str:
-        return f"serve:{self.base_arch}:{self.shape}"
-
-
-def serve_cell(arch: str, trace: str) -> ServeCell:
+def serve_cell(arch: str, trace: str) -> CellSpec:
+    """One (arch, trace) serving cell, arch ``serve-<arch>``."""
     from repro.configs import list_archs
     from repro.serving.traffic import trace_names
     if arch not in list_archs():
@@ -119,17 +95,7 @@ def serve_cell(arch: str, trace: str) -> ServeCell:
     if trace not in trace_names():
         raise ValueError(f"unknown trace {trace!r} "
                          f"(known: {', '.join(trace_names())})")
-    return ServeCell(SERVE_ARCH_PREFIX + arch, trace, False)
-
-
-def parse_serve_cell(item: str) -> ServeCell:
-    """Parse one ``serve:<arch>:<trace>`` cell spec (the string
-    :meth:`ServeCell.spec` emits and the fabric round-trips)."""
-    parts = item.strip().split(":")
-    if len(parts) != 3 or parts[0] != "serve":
-        raise ValueError(f"bad serve cell spec {item!r} "
-                         "(want serve:<arch>:<trace>)")
-    return serve_cell(parts[1], parts[2])
+    return CellSpec(kind("serve").prefix + arch, trace, False)
 
 
 #: the knobs a serve cell's stage tree proposes deltas on — the serving
@@ -144,7 +110,7 @@ def serve_signature(arch: str, shape: str, multi_pod: bool = False
     """Warm-start similarity features for a serve cell (counterpart of
     :func:`repro.core.history.cell_signature`)."""
     from repro.configs import get_config
-    base = arch[len(SERVE_ARCH_PREFIX):]
+    base = base_name(arch)
     try:
         family = get_config(base).family
     except KeyError:
@@ -335,7 +301,7 @@ class ServeEvaluator:
     def __call__(self, wl: Workload, rt: TunableConfig) -> TrialResult:
         t0 = time.time()
         try:
-            if not is_serve_workload(wl):
+            if kind_of(wl.arch).name != "serve":
                 raise TrialError(f"{wl.key()} is not a serve cell")
             SPACE.validate(rt)
             for name in ("max_wave_size", "wave_admission"):
@@ -376,14 +342,6 @@ class CachedServe(CachedMeasure):
 def make_serve_evaluator(slo_ttft: Optional[float] = None,
                          cache: Optional[TimingCache] = None
                          ) -> CachedServe:
-    """The serve branch of the campaign's dispatch evaluator."""
+    """The serve cells' trial-tier evaluator (core/cells.py)."""
     return CachedServe(ServeEvaluator(slo_ttft=slo_ttft), cache=cache,
                        repeats=1)
-
-
-def make_evaluator() -> "Any":
-    """Zero-arg factory (``--evaluator repro.serving.evaluator:
-    make_evaluator``): the standard dispatch stack with the serve tier
-    attached — identical to the campaign default."""
-    from repro.core.kernel_cell import DispatchEvaluator
-    return DispatchEvaluator()

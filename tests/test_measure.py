@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core.campaign import Campaign, CellSpec, parse_cells
+from repro.core.cells import kind, kind_of
 from repro.core.history import TrialHistory
 from repro.core.measure import (CachedMeasure, ReducedWallClock,
                                 TimingCache, measure_key, select_top_k)
@@ -323,6 +324,8 @@ def test_sensitivity_strategy_not_measurable(tmp_path):
 def test_parse_kernel_cells():
     cells = parse_cells("kernel:flash_attention:tiny,smollm-135m:train_4k")
     assert cells[0].arch == "kernel-flash_attention"
+    assert [kind_of(c.arch).name for c in cells] == ["kernel", "step"]
+    assert cells[0] == kind("kernel").parse(cells[0].spec())
     assert cells[0].spec() == "kernel:flash_attention:tiny"
     assert cells[1].arch == "smollm-135m"
     with pytest.raises(ValueError):

@@ -9,6 +9,8 @@ import pytest
 
 from repro.core.campaign import (Campaign, CellSpec, parse_cells,
                                  tuning_fingerprint)
+from repro.core.cells import (DispatchEvaluator, default_evaluator,
+                              default_measured_evaluator, kind, kind_of)
 from repro.core.history import cell_signature
 from repro.core.params import default_config
 from repro.core.space import SPACE
@@ -16,15 +18,16 @@ from repro.core.trial import FAILURE_DETERMINISTIC, TrialResult
 from repro.serving.canary import (SLO_QDELAY_FLOOR_S, SLO_TTFT_FLOOR_S,
                                   PromotionBoard, SLOGuard,
                                   SLOViolation, promote_winners)
+from repro.serving import evaluator as serve_mod
 from repro.serving.evaluator import (SERVE_KNOBS, CachedServe,
-                                     ServeEvaluator, parse_serve_cell,
-                                     serve_cell, serve_signature,
-                                     serve_stages)
+                                     ServeEvaluator, serve_cell,
+                                     serve_signature, serve_stages)
+from repro.serving.traffic import Trace
 
 
 # ------------------------------------------------------------------ cells
 def test_parse_serve_cell_roundtrip():
-    cell = parse_serve_cell("serve:smollm-135m:poisson_tiny")
+    cell = kind("serve").parse("serve:smollm-135m:poisson_tiny")
     assert cell.arch == "serve-smollm-135m"
     assert cell.shape == "poisson_tiny"
     assert cell.spec() == "serve:smollm-135m:poisson_tiny"
@@ -39,7 +42,7 @@ def test_parse_serve_cell_roundtrip():
                                  "kernel:smollm-135m:poisson_tiny"])
 def test_parse_serve_cell_rejects_malformed(bad):
     with pytest.raises(ValueError):
-        parse_serve_cell(bad)
+        kind("serve").parse(bad)
 
 
 def test_serve_cell_validates_arch_and_trace():
@@ -52,6 +55,8 @@ def test_serve_cell_validates_arch_and_trace():
 def test_serve_signature_via_history_dispatch():
     sig = cell_signature("serve-smollm-135m", "poisson_tiny", False)
     assert sig == serve_signature("serve-smollm-135m", "poisson_tiny")
+    assert sig == kind_of("serve-smollm-135m").signature(
+        "serve-smollm-135m", "poisson_tiny", False)
     assert sig["kind"] == "serve"
     assert sig["active_knobs"] == list(SERVE_KNOBS)
 
@@ -94,7 +99,6 @@ def test_step_campaign_fingerprints_unchanged_by_serving_stack(tmp_path):
     """The serving-aware dispatch evaluator must leave every non-serving
     campaign bit-identical to the bare step evaluator (the PR-7
     regression bar)."""
-    from repro.core.kernel_cell import DispatchEvaluator
     cells = [CellSpec("smollm-135m", "train_4k"),
              CellSpec("glm4-9b", "decode_32k")]
     bf = lambda spec: default_config(shard_strategy="fsdp_tp",
@@ -102,8 +106,9 @@ def test_step_campaign_fingerprints_unchanged_by_serving_stack(tmp_path):
     bare = Campaign(cells, evaluator=_surface, baseline_factory=bf,
                     checkpoint_dir=tmp_path / "bare").run()
     dispatched = Campaign(cells,
-                          evaluator=DispatchEvaluator(step=_surface,
-                                                      slo_ttft=3.0),
+                          evaluator=DispatchEvaluator({
+                              **default_evaluator(slo_ttft=3.0).evaluators,
+                              "step": _surface}),
                           baseline_factory=bf,
                           checkpoint_dir=tmp_path / "disp").run()
     assert list(bare) == list(dispatched)
@@ -170,7 +175,7 @@ def test_cost_of_combines_ttft_qdelay_decode():
     assert ServeEvaluator.cost_of({"served": 0}) == 0.0
 
 
-def test_cached_serve_key_folds_trace_content_and_slo():
+def test_cached_serve_key_folds_trace_content_and_slo(monkeypatch):
     wl = serve_cell("smollm-135m", "poisson_tiny").workload()
     wl2 = serve_cell("smollm-135m", "bursty_tiny").workload()
     rt = default_config()
@@ -184,6 +189,15 @@ def test_cached_serve_key_folds_trace_content_and_slo():
     assert CachedServe(ServeEvaluator(),
                        repeats=1)._key(wl, rt.replace(
                            max_wave_size=8)) != k
+    # the measured tier's serve entry is keyed the same way: an edited
+    # trace under the same name never reuses a stale measured cost
+    measured = default_measured_evaluator().evaluators["serve"]
+    km = measured._key(wl, rt)
+    assert km == CachedServe(ServeEvaluator(), repeats=3)._key(wl, rt)
+    tr = serve_mod.get_trace(wl.shape)
+    monkeypatch.setattr(serve_mod, "get_trace",
+                        lambda name: Trace(tr.meta, tr.requests[:-1]))
+    assert measured._key(wl, rt) != km
 
 
 def test_non_serve_workload_is_a_crashed_trial():
