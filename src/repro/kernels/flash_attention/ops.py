@@ -17,6 +17,12 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
     """q: (B, S, H, hd), k/v: (B, S, Hkv, hd) with ``H % Hkv == 0``
     (GQA: not repeated to H heads) -> (B, S, H, hd).
 
+    ``block_q`` query rows against ``block_kv`` keys make one
+    online-softmax update: the tiles set the score working set and the
+    number of updates.  K/V stay in VMEM across a KV head's query tiles
+    wherever they fit (``flash_attention.kv_span``), so at prefill
+    lengths the tiles do not change how often K/V cross HBM.
+
     Blocks are fitted to the dtype's tile (kernels/tiling.py); a ragged
     S with no aligned divisor is padded at the end and sliced back."""
     S = q.shape[1]

@@ -80,7 +80,9 @@ def _sds(shape, dtype, sharding):
     (16, 2048, 9, 3, 64, 512, 512),
     (16, 2048, 9, 3, 64, 512, 128),
     (16, 2048, 9, 3, 64, 128, 512),
-    (2, 2048, 9, 1, 64, 512, 512)])     # a group of 9 split to 3 for VMEM
+    (2, 2048, 9, 1, 64, 512, 512),      # a group of 9 split to 3 for VMEM
+    (8, 4096, 16, 16, 128, 128, 128),   # olmoe-1b-7b-4l prefill: one span
+    (1, 32768, 16, 16, 128, 128, 128)]) # 32k: K/V in several spans
 def test_flash_attention_compiles(one_chip, mosaic, B, S, H, Hkv, hd, bq,
                                   bk):
     from repro.kernels.flash_attention.ops import flash_attention

@@ -38,14 +38,41 @@ _FLASH_SHAPES = [(1, 1, 1, 128, 64), (2, 3, 3, 256, 64), (1, 2, 2, 512, 128),
 #: (block_q, block_kv): small tiles, and the tile knobs' domain
 #: (128, 256, 512) with block_q != block_kv
 _FLASH_BLOCKS = [(64, 64), (128, 128), (64, 128), (256, 256), (512, 512),
-                 (128, 512), (512, 256)]
+                 (128, 512), (512, 256), (256, 128), (128, 256)]
+
+
+@pytest.fixture(params=["one", "several"])
+def kv_spans(request, monkeypatch):
+    """Runs a flash test with K/V resident as one span, as the planner
+    picks at the tests' sizes, or as several: the VMEM budget then holds
+    two kv tiles of K/V beside the step's query heads."""
+    from repro.kernels.flash_attention import flash_attention as fa_kernel
+    plan, seen = fa_kernel.kv_span, []
+
+    def kv_span(S, hd, dtype, block_q, block_kv, group):
+        if request.param == "several":
+            monkeypatch.setattr(fa_kernel, "VMEM_BUDGET",
+                                fa_kernel._vmem_bytes(group, block_q, block_kv,
+                                                      2 * block_kv, hd, dtype))
+        span = plan(S, hd, dtype, block_q, block_kv, group)
+        n = S // block_kv
+        assert span == (S if request.param == "one" else
+                        block_kv * (2 if n % 2 == 0 else 1)), (S, span)
+        seen.append(span)
+        return span
+
+    monkeypatch.setattr(fa_kernel, "kv_span", kv_span)
+    fa_kernel.flash_attention_bhsd.clear_cache()    # trace with this plan
+    yield
+    fa_kernel.flash_attention_bhsd.clear_cache()
+    assert seen, "the kernel was not traced under the plan"
 
 
 @pytest.mark.parametrize("B,H,Hkv,S,hd,bq,bk", [
     s + b for s in _FLASH_SHAPES for b in _FLASH_BLOCKS
     if s[3] % b[0] == 0 and s[3] % b[1] == 0])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_flash_attention_sweep(B, H, Hkv, S, hd, bq, bk, dtype):
+def test_flash_attention_sweep(B, H, Hkv, S, hd, bq, bk, dtype, kv_spans):
     q, k, v = _qkv(jax.random.PRNGKey(S + hd), B, S, H, Hkv, hd, dtype)
     out = fa_ops.flash_attention(q, k, v, causal=True, block_q=bq,
                                  block_kv=bk)
@@ -73,14 +100,35 @@ def test_flash_attention_split_group(monkeypatch):
                                atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("B,S,H,Hkv,hd,spans", [
+    (16, 2048, 9, 3, 64, 1),        # smollm-135m.prefill-2k
+    (8, 4096, 16, 16, 128, 1),      # olmoe-1b-7b-4l.prefill-8x4k
+    (1, 32768, 16, 16, 128, 4)])    # the tuner's 32k kernel cells
+def test_flash_attention_kv_span(B, S, H, Hkv, hd, spans):
+    """The K/V span at 128 x 128 bf16 tiles: the whole sequence at the
+    benchmark's shapes, read once, with the GQA group whole; several
+    spans, each whole kv tiles dividing S, within the VMEM budget, at
+    32k."""
+    from repro.kernels.flash_attention.flash_attention import (
+        VMEM_BUDGET, _vmem_bytes, head_group, kv_passes, kv_span)
+    g = head_group(H // Hkv, 128, 128, hd, jnp.bfloat16)
+    assert g == H // Hkv
+    span = kv_span(S, hd, jnp.bfloat16, 128, 128, g)
+    assert S // span == spans and span % 128 == 0 and S % span == 0
+    assert _vmem_bytes(g, 128, 128, span, hd, jnp.bfloat16) <= VMEM_BUDGET
+    passes = kv_passes(S, span, 128, causal=True)
+    assert passes == 1.0 if spans == 1 else passes > 1.0
+
+
 @pytest.mark.parametrize("S,bq,bk", [(2048, 128, 128), (1024, 128, 512),
                                      (1024, 512, 128), (384, 128, 256),
-                                     (304, 304, 16)])
+                                     (304, 304, 16), (2048, 128, 2048)])
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_attention_pair_table(S, bq, bk, causal):
     """The grid walks exactly the live (q tile, kv tile) pairs, each q
     tile's run consecutive from kv tile 0 and ending on its diagonal
-    (every kv tile when not causal)."""
+    (every kv tile when not causal); with the whole sequence as its kv
+    block (one span), one pair a q tile."""
     from repro.kernels.flash_attention.flash_attention import (
         pair_table, unpack)
     nq, nk = S // bq, S // bk
@@ -96,6 +144,8 @@ def test_flash_attention_pair_table(S, bq, bk, causal):
         assert last[run].tolist() == [0] * end + [1]
     if (S, bq, bk, causal) == (2048, 128, 128, True):
         assert len(qi) == 136         # of 256 tiles
+    if bk == S:
+        assert qi.tolist() == list(range(nq)) and last.all()
 
 
 @pytest.mark.parametrize("shape", [(8, 64), (3, 37, 512), (2, 4, 16, 128)])
@@ -151,11 +201,13 @@ def test_flash_decode_sweep(B, H, Hkv, S, hd, length, kv_dtype):
                                atol=2e-2, rtol=2e-2)
 
 
-@pytest.mark.parametrize("H,Hkv,S,bq,bk", [
-    (2, 2, 128, 128, 128), (9, 3, 512, 128, 512), (4, 1, 512, 256, 128),
-    (9, 3, 300, 128, 128)])          # ragged: a padded kv_len tail
-def test_flash_attention_noncausal(H, Hkv, S, bq, bk):
-    q, k, v = _qkv(jax.random.PRNGKey(7), 2, S, H, Hkv, 64)
+@pytest.mark.parametrize("H,Hkv,S,hd,bq,bk", [
+    (2, 2, 128, 64, 128, 128), (9, 3, 512, 64, 128, 512),
+    (4, 1, 512, 64, 256, 128),
+    (9, 3, 300, 64, 128, 128),       # ragged: a padded kv_len tail
+    (2, 2, 1024, 128, 128, 256)])
+def test_flash_attention_noncausal(H, Hkv, S, hd, bq, bk, kv_spans):
+    q, k, v = _qkv(jax.random.PRNGKey(7), 2, S, H, Hkv, hd)
     out = fa_ops.flash_attention(q, k, v, causal=False, block_q=bq,
                                  block_kv=bk)
     ref = _flash_ref(q, k, v, causal=False)
@@ -193,7 +245,7 @@ def test_fit_block():
 @pytest.mark.parametrize("S", [192, 96, 300])
 @pytest.mark.parametrize("blocks", [(128, 128), (256, 64)])
 @pytest.mark.parametrize("H,Hkv", [(2, 2), (9, 3)])
-def test_flash_attention_ragged(S, blocks, H, Hkv):
+def test_flash_attention_ragged(S, blocks, H, Hkv, kv_spans):
     bq, bk = blocks
     q, k, v = _qkv(jax.random.PRNGKey(S), 1, S, H, Hkv, 64)
     out = fa_ops.flash_attention(q, k, v, causal=True, block_q=bq,
